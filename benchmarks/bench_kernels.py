@@ -1,10 +1,10 @@
 """Kernel micro-bench: Pallas (interpret on CPU) vs pure-jnp reference.
 
-On this CPU container the Pallas interpreter is NOT a performance target —
-the numbers recorded here document (a) correctness at benchmark shapes and
-(b) the jnp-reference wall time that the roofline's memory-term is sanity-
-checked against.  On TPU hardware the same ``ops.py`` entry points dispatch
-the compiled kernels.
+A CPU bench: the Pallas interpreter and XLA:CPU are NOT a performance
+target — the numbers recorded here document (a) correctness at benchmark
+shapes and (b) the jnp-reference wall time that the roofline's memory-term
+is sanity-checked against.  None of them is a device time.  Run it with
+``JAX_PLATFORMS=cpu``: its sharded-lookup child refuses a TPU host.
 """
 from __future__ import annotations
 
@@ -49,9 +49,10 @@ from repro.core.allocation import LMAParams, alloc_lma
 from repro.core.memory import init_memory, lookup
 from repro.core.signatures import synthetic_dense_store
 from repro.dist.context import use_mesh
+from repro.launch.mesh import make_mesh
 from repro.dist.sharded_memory import sharded_lma_lookup
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 B, D, M, N = 4096, 32, 1 << 21, 8192
 lma = LMAParams(d=D, m=M, n_h=4, max_set=32, seed=7)
 store = synthetic_dense_store(N, 64, max_set=32, seed=1)
@@ -129,6 +130,14 @@ print(json.dumps({
 
 
 def bench_sharded_lookup() -> dict:
+    """A CPU bench: the child forces 8 virtual host devices.  It refuses to
+    start on a TPU host — this process already holds the chip, so a child
+    that reached for it would fail or hang on the device lock."""
+    if jax.default_backend() == "tpu":
+        raise RuntimeError(
+            "bench_sharded_lookup is a CPU bench (8 virtual host devices in "
+            "a child process) and this process holds the TPU; run the "
+            "kernel bench with JAX_PLATFORMS=cpu")
     env = dict(os.environ)
     src = os.path.join(os.path.dirname(__file__), "..", "src")
     env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")

@@ -26,11 +26,12 @@ store = synthetic_dense_store(cfg.embedding.total_vocab, 16,
                               max_set=cfg.embedding.lma.max_set)
 bufs = make_buffers(cfg.embedding, store)
 params = recsys.init(jax.random.key(0), cfg)
-fwd = jax.jit(lambda b: recsys.forward(params, cfg, b, bufs))
+fwd = jax.jit(lambda p, b, bufs: recsys.forward(p, cfg, b, bufs))
 
 
 def score_fn(batch):
-    return np.asarray(fwd({k: jnp.asarray(v) for k, v in batch.items()}))
+    return np.asarray(fwd(params, {k: jnp.asarray(v) for k, v in batch.items()},
+                          bufs))
 
 
 def main():

@@ -63,19 +63,19 @@ def train(kind: str, steps: int, gen: CTRGenerator, ckpt_dir: str):
     trainer = Trainer(
         TrainerConfig(total_steps=steps, ckpt_dir=ckpt_dir, ckpt_every=100,
                       log_every=max(steps // 6, 1)),
-        lambda p, b: recsys.loss_fn(p, cfg, b, bufs),
-        params, opt_lib.adagrad(0.05), batch_fn)
+        lambda p, b, bufs: recsys.loss_fn(p, cfg, b, bufs),
+        params, opt_lib.adagrad(0.05), batch_fn, loss_args=(bufs,))
     trainer.install_signal_handlers()     # SIGTERM -> checkpoint & exit
     out = trainer.fit()
     print(f"[{kind}] finished at step {out['step']}, loss {out['loss']:.4f}, "
           f"stragglers {out.get('straggler_steps', 0)}")
 
     ev = StreamingEval()
-    fwd = jax.jit(lambda p, b: recsys.forward(p, cfg, b, bufs))
+    fwd = jax.jit(lambda p, b, bufs: recsys.forward(p, cfg, b, bufs))
     for i in range(8):
         b = gen.batch(2048, 900_000 + i)
         jb = {k: jnp.asarray(v) for k, v in b.items() if k != "label"}
-        ev.add(b["label"], np.asarray(fwd(trainer.params, jb)))
+        ev.add(b["label"], np.asarray(fwd(trainer.params, jb, bufs)))
     met = ev.compute()
     print(f"[{kind}] eval: auc={met['auc']:.4f} logloss={met['logloss']:.4f} "
           f"acc={met['accuracy']:.4f} (n={met['n']})")

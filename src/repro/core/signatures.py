@@ -53,22 +53,29 @@ def build_signature_store(
     at ``max_per_value`` sample ids (reservoir-free head cap: D' rows are already
     an i.i.d. subsample, so the head of each list is itself i.i.d.).
     """
-    buckets: list[list[int]] = [[] for _ in range(n_values)]
+    # one (value, sample id) pair per row entry, in row order; a stable sort
+    # by value keeps each value's sample ids in arrival order, so the head
+    # cap keeps the first ``max_per_value`` of them
+    vals = []
     for sample_id, row in enumerate(rows):
         if n_samples is not None and sample_id >= n_samples:
             break
-        for v in np.asarray(row).ravel():
-            b = buckets[int(v)]
-            if len(b) < max_per_value:
-                b.append(sample_id)
-    lengths = np.array([len(b) for b in buckets], dtype=np.int32)
+        vals.append(np.asarray(row, np.int64).ravel())
+    counts = np.fromiter(map(len, vals), np.int64, len(vals))
+    vals = np.concatenate(vals) if vals else np.zeros(0, np.int64)
+    if vals.size and (vals.min() < 0 or vals.max() >= n_values):
+        raise IndexError(f"value ids must lie in [0, {n_values})")
+    sample_ids = np.repeat(np.arange(len(counts), dtype=np.uint32), counts)
+    order = np.argsort(vals, kind="stable")
+    vals, sample_ids = vals[order], sample_ids[order]
+    per_value = np.bincount(vals, minlength=n_values)
+    first = np.cumsum(per_value) - per_value
+    keep = np.arange(vals.size) - first[vals] < max_per_value
+    lengths = np.minimum(per_value, max_per_value).astype(np.int32)
     offsets = np.zeros(n_values + 1, dtype=np.int32)
     np.cumsum(lengths, out=offsets[1:])
-    flat = np.empty(int(offsets[-1]), dtype=np.uint32)
-    for v, b in enumerate(buckets):
-        flat[offsets[v] : offsets[v + 1]] = b
     return SignatureStore(
-        flat=jnp.asarray(flat),
+        flat=jnp.asarray(sample_ids[keep]),
         offsets=jnp.asarray(offsets),
         lengths=jnp.asarray(lengths),
     )
@@ -141,11 +148,12 @@ def densify_store(store: SignatureStore, max_set: int,
     n = lengths.shape[0]
     rows = max(n_rows or n, n)
     sets = np.full((rows, max_set), DenseSignatureStore.PAD, np.uint32)
-    for v in range(n):
-        k = min(int(lengths[v]), max_set)
-        sets[v, :k] = flat[offsets[v] : offsets[v] + k]
+    k = np.minimum(lengths, max_set)
+    v = np.repeat(np.arange(n), k)
+    j = np.arange(v.size) - np.repeat(np.cumsum(k) - k, k)
+    sets[v, j] = flat[offsets[v] + j]
     out_len = np.zeros(rows, np.int32)
-    out_len[:n] = np.minimum(lengths, max_set)
+    out_len[:n] = k
     return DenseSignatureStore(sets=jnp.asarray(sets),
                                lengths=jnp.asarray(out_len))
 

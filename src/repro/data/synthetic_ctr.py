@@ -73,6 +73,10 @@ class CTRGenerator:
         self.cluster_values = []
         for f in range(spec.n_fields):
             lists = [np.where(self.value_cluster[f] == c)[0] for c in range(K)]
+            # a field with fewer values than clusters (Criteo has 3- and
+            # 4-value fields) draws its empty clusters from the whole field
+            lists = [ls if len(ls) else np.arange(spec.vocab_sizes[f])
+                     for ls in lists]
             self.cluster_values.append(lists)
         self.offsets = np.concatenate(
             [[0], np.cumsum(np.asarray(spec.vocab_sizes, np.int64))])

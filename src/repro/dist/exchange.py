@@ -457,9 +457,10 @@ def fused_slab_eligible(m: int, n_model: int, itemsize: int = 4) -> bool:
     and the dryrun meta so their pricing can never disagree.  ``itemsize``
     is the pool dtype's (callers with a concrete array pass it; 4 = the f32
     default)."""
+    from repro.kernels.dispatch import pallas_allowed
     from repro.kernels.fused_embed import ops as fe
-    return fe.fused_enabled() and fe.fused_supported(m // max(n_model, 1),
-                                                     itemsize)
+    return (pallas_allowed("fused_embed") and fe.fused_enabled()
+            and fe.fused_supported(m // max(n_model, 1), itemsize))
 
 
 def fused_chunk_eligible(m: int, n_model: int, itemsize: int = 4) -> bool:
@@ -471,8 +472,10 @@ def fused_chunk_eligible(m: int, n_model: int, itemsize: int = 4) -> bool:
     production shape) still chunk-fuse.  Shared by ``resolve_exchange``,
     the sharded_memory drivers, and the dryrun meta, exactly like the slab
     gate — modeled and runtime dispatch cannot diverge."""
+    from repro.kernels.dispatch import pallas_allowed
     from repro.kernels.fused_embed import ops as fe
-    return (n_model > 1 and m % n_model == 0 and fe.fused_enabled()
+    return (n_model > 1 and m % n_model == 0 and pallas_allowed("fused_embed")
+            and fe.fused_enabled()
             and fe.fused_chunk_supported(m // n_model, itemsize))
 
 

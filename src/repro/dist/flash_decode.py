@@ -24,7 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.dist.sharding import shard_map
+from jax import shard_map
 
 _NEG_INF = -1e30
 

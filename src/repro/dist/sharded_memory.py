@@ -65,7 +65,7 @@ from repro.core.memory import lookup
 from repro.core.signatures import DenseSignatureStore
 from repro.dist import exchange as exl
 from repro.dist.exchange import local_gather_psum  # noqa: F401  (public API)
-from repro.dist.sharding import shard_map
+from jax import shard_map
 
 
 _model_size = exl.model_size

@@ -31,17 +31,6 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
 
 
-def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = False):
-    """Version-portable ``shard_map``: new jax exposes ``jax.shard_map``
-    (``check_vma``), 0.4.x has ``jax.experimental.shard_map`` (``check_rep``)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
-
-
 class _AxisSet:
     """Named axis-set placeholder, expanded against a concrete mesh."""
 

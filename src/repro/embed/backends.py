@@ -58,9 +58,10 @@ def fused_eligible(cfg: EmbeddingConfig, scheme: Scheme, params: dict) -> bool:
     # split path's bit-exact twin when the pool really has m slots
     if mem.shape[0] != scheme.memory_slots(cfg):
         return False
+    from repro.kernels.dispatch import pallas_allowed
     from repro.kernels.fused_embed import ops as fe
-    return fe.fused_enabled() and fe.fused_supported(mem.shape[0],
-                                                     mem.dtype.itemsize)
+    return (pallas_allowed("fused_embed") and fe.fused_enabled()
+            and fe.fused_supported(mem.shape[0], mem.dtype.itemsize))
 
 
 class SplitBackend:
@@ -197,7 +198,8 @@ def resolve_backend(cfg: EmbeddingConfig, params: dict,
     table-family schemes (they embed directly, no shared pool).  Priority:
     tiered (the buffers carry tier remap state — the pool exceeded the
     per-device budget and ``repro.tier`` split it) > sharded (a mesh is
-    installed) > fused (engine enabled + spec + VMEM fit) > split.
+    installed) > fused (engine enabled + spec + VMEM fit + the TPU
+    dispatch rule of ``repro.kernels.dispatch``) > split.
     ``fused_eligible`` independently rejects tiered pools: the compact pool
     has fewer than ``memory_slots`` slots, so the slab gate fails closed
     even if a caller forgets to pass ``buffers``.

@@ -141,7 +141,8 @@ def _chunk_block_m(m_local: int, itemsize: int) -> int:
 
 
 def _default_interpret(interpret):
-    return jax.default_backend() != "tpu" if interpret is None else interpret
+    from repro.kernels.dispatch import platform
+    return platform() != "tpu" if interpret is None else interpret
 
 
 def _loc_inputs(spec: FusedSpec, sets, gids, support):

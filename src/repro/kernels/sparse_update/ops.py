@@ -23,8 +23,7 @@ from __future__ import annotations
 
 import os
 
-import jax
-
+from repro.kernels import dispatch
 from repro.kernels.sparse_update import kernel as _k
 from repro.kernels.sparse_update import ref as _r
 
@@ -70,14 +69,16 @@ def sparse_update(algo: str, indices, values, states: tuple, *,
 
     ``unique=False`` declares sorted-with-duplicates indices (bucketed
     layout) and turns on the in-kernel duplicate fold in whichever backend
-    runs.  ``interpret=None``: Pallas (compiled) on TPU when eligible, jnp
-    ref elsewhere.  ``interpret=True`` forces the Pallas kernel in
+    runs.  ``interpret=None``: Pallas (compiled) on TPU when eligible and
+    the TPU dispatch rule (``repro.kernels.dispatch``) admits it, jnp ref
+    elsewhere.  ``interpret=True`` forces the Pallas kernel in
     interpret mode (test hook); ``interpret=False`` forces compiled Pallas.
     """
     assert algo in ALGOS, algo
     use_pallas = (interpret is not None
                   and _shapes_ok(algo, values, states)) or (
-        jax.default_backend() == "tpu"
+        dispatch.platform() == "tpu"
+        and dispatch.pallas_allowed("sparse_update")
         and _pallas_ok(algo, indices, values, states))
     if use_pallas and states:
         interp = bool(interpret)

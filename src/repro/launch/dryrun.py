@@ -124,10 +124,7 @@ def run_cell(arch_id: str, shape_id: str, multi_pod: bool,
         t_compile = time.time() - t0 - t_lower
 
     mem = compiled.memory_analysis()
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):  # jax<0.5 returns [dict], newer: dict
-        cost = cost[0] if cost else {}
-    cost = dict(cost)
+    cost = dict(compiled.cost_analysis())
     colls = parse_collectives(compiled.as_text())
     result = {
         "arch": arch_id, "shape": shape_id, "mesh": mesh_name,

@@ -339,9 +339,12 @@ def _tier_meta(rcfg, B: int, mesh=None) -> dict:
                      "host_fetch_bytes_per_step": int(fetch)}}
 
 
-def _recsys_bundle(arch: ArchConfig, shape_id: str, mesh) -> Bundle:
-    t = RECSYS_SHAPE_TABLE[shape_id]
-    rcfg = arch.make_model(shape_id)
+def _recsys_bundle(arch: ArchConfig, shape_id: str, mesh,
+                   batch: int | None = None, smoke: bool = False) -> Bundle:
+    t = dict(RECSYS_SHAPE_TABLE[shape_id])
+    if batch is not None:
+        t["batch"] = batch
+    rcfg = arch.make_smoke() if smoke else arch.make_model(shape_id)
     rules = shd.recsys_rules()
     param_shapes = jax.eval_shape(lambda: recsys.init(jax.random.key(0), rcfg))
     param_sh = _shardings(mesh, param_shapes, rules)
@@ -510,14 +513,20 @@ def _gnn_bundle(arch: ArchConfig, shape_id: str, mesh) -> Bundle:
         donate=(0, 1), meta={"kind": "train", "nodes": N, "edges": E})
 
 
-def build_cell(arch_id: str, shape_id: str, mesh) -> Bundle:
+def build_cell(arch_id: str, shape_id: str, mesh, *, batch: int | None = None,
+               smoke: bool = False) -> Bundle:
+    """``batch`` overrides the shape's examples per step and ``smoke`` takes
+    the arch's reduced config (recsys cells only: the on-chip smoke run
+    drives a cell at a per-chip batch, and rehearses it on the CPU)."""
     arch = get_config(arch_id)
     if shape_id not in arch.shapes:
         raise ValueError(f"{arch_id} does not define shape {shape_id}")
+    if arch.family == "recsys":
+        return _recsys_bundle(arch, shape_id, mesh, batch=batch, smoke=smoke)
+    if batch is not None or smoke:
+        raise ValueError("batch / smoke overrides apply to recsys cells only")
     if arch.family == "lm":
         return _lm_bundle(arch, shape_id, mesh)
-    if arch.family == "recsys":
-        return _recsys_bundle(arch, shape_id, mesh)
     if arch.family == "gnn":
         return _gnn_bundle(arch, shape_id, mesh)
     raise ValueError(arch.family)

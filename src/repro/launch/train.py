@@ -26,6 +26,7 @@ from repro.core.signatures import build_signature_store, densify_store
 from repro.data.lm_data import LMGenerator
 from repro.data.metrics import StreamingEval
 from repro.data.synthetic_ctr import CTRGenerator, CTRSpec, DINGenerator, DINSpec
+from repro.launch.steps import store_rows
 from repro.models import recsys, transformer
 from repro.optim import optimizers as opt_lib
 from repro.optim import sparse as sparse_lib
@@ -130,7 +131,7 @@ def _maybe_tier(cfg, arch, params, bufs, batch_fn, budget_mb):
             f"batch")
     store = TieredStore(mem, hot_slots, block=block, stage_blocks=cap)
 
-    def tiered_loss(p, b):
+    def tiered_loss(p, b, bufs):
         clean, tier = split_batch(b)
         return recsys.loss_fn(p, cfg, clean, {**bufs, **tier})
 
@@ -144,7 +145,10 @@ def _maybe_tier(cfg, arch, params, bufs, batch_fn, budget_mb):
     return params, tiered_loss, TierController(store, batch_fn, plan_fn)
 
 
-def _recsys_setup(arch, cfg, n_s: int, batch: int):
+def recsys_setup(arch, cfg, n_s: int, batch: int):
+    """Data generator, embedding buffers (the D' store from ``n_s`` rows
+    for lma), ``batch_fn(step)`` and ``loss_fn(params, batch, bufs)`` —
+    the buffers reach the jitted step as an argument, never a closure."""
     e = cfg.embedding
     if cfg.model == "din":
         gen = DINGenerator(DINSpec(n_items=e.vocab_sizes[0], hist_len=max(
@@ -161,7 +165,10 @@ def _recsys_setup(arch, cfg, n_s: int, batch: int):
         print(f"building D' ({n_s} rows)...")
         store = build_signature_store(gen.rows_for_signatures(n_s),
                                       e.total_vocab, max_per_value=e.lma.max_set)
-        bufs = make_buffers(e, densify_store(store, e.lma.max_set))
+        # rows padded like the dry-run's buffer specs, so one store serves
+        # the single-device step and the 'model'-sharded one
+        bufs = make_buffers(e, densify_store(
+            store, e.lma.max_set, n_rows=store_rows(e.total_vocab)))
     elif scheme.buffer_source == "id_counts":
         print(f"counting observed ids ({n_s} rows)...")
         counts = np.zeros(e.total_vocab, np.int64)
@@ -172,7 +179,8 @@ def _recsys_setup(arch, cfg, n_s: int, batch: int):
     def batch_fn(step):
         return {k: jnp.asarray(v) for k, v in gen.batch(batch, step).items()}
 
-    return gen, bufs, batch_fn, (lambda p, b: recsys.loss_fn(p, cfg, b, bufs))
+    return gen, bufs, batch_fn, (
+        lambda p, b, bufs: recsys.loss_fn(p, cfg, b, bufs))
 
 
 def main(argv=None):
@@ -220,6 +228,8 @@ def main(argv=None):
                     help="delta-chain length before forcing a full base "
                          "checkpoint (bounds restore replay cost)")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import setup_compile_cache
+    print(f"compile cache: {setup_compile_cache()}")
 
     if args.exchange is not None:
         from repro.dist import exchange as exl
@@ -233,7 +243,7 @@ def main(argv=None):
 
     tier_ctrl = None
     if arch.family == "recsys":
-        gen, bufs, batch_fn, loss_fn = _recsys_setup(
+        gen, bufs, batch_fn, loss_fn = recsys_setup(
             arch, cfg, args.n_signatures, args.batch)
         params = recsys.init(jax.random.key(0), cfg)
         from repro.tier import tier_budget_mb
@@ -250,7 +260,7 @@ def main(argv=None):
             b = gen.batch(min(args.batch, 16), 64, step)
             return {k: jnp.asarray(v) for k, v in b.items()}
 
-        def loss_fn(p, b):
+        def loss_fn(p, b, bufs):
             return transformer.loss_fn(p, cfg, b["tokens"], b["labels"])
 
         params = transformer.init(jax.random.key(0), cfg)
@@ -283,7 +293,7 @@ def main(argv=None):
         loss_fn, params, make_optimizer(arch, sparse_ok=tier_ctrl is None),
         batch_fn, faults=injector,
         sparse_grads=False if tier_ctrl is not None else None,
-        tier=tier_ctrl)
+        tier=tier_ctrl, loss_args=(bufs,))
     if trainer.sparse_grads:
         from repro.dist import exchange as exl
         print("sparse memory-pool updates ON (REPRO_SPARSE_GRADS=0 for the "
@@ -304,11 +314,11 @@ def main(argv=None):
                        if tier_ctrl is not None else trainer.params)
         if tier_ctrl is not None:
             print(f"tier: {trainer.tier.stats()}")
-        fwd = jax.jit(lambda p, b: recsys.forward(p, cfg, b, bufs))
+        fwd = jax.jit(lambda p, b, bufs: recsys.forward(p, cfg, b, bufs))
         for i in range(args.eval_batches):
             b = gen.batch(2048, 700_000 + i)
             jb = {k: jnp.asarray(v) for k, v in b.items() if k != "label"}
-            ev.add(b["label"], np.asarray(fwd(eval_params, jb)))
+            ev.add(b["label"], np.asarray(fwd(eval_params, jb, bufs)))
         print(f"eval: {ev.compute()}")
 
 

@@ -217,8 +217,7 @@ def moe_apply_sharded(p: dict, cfg: MoEConfig, x: jax.Array, mesh,
             aux = jax.lax.pmean(aux, dp_axes)
         return out, aux
 
-    from repro.dist.sharding import shard_map
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(None, None), spec_g, spec_g, spec_d, x_spec),
         out_specs=(x_spec, P()),

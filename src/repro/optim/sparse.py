@@ -217,6 +217,26 @@ def _bucket_sharding(*arrs, axes: int = 1):
     return tuple(out) if len(out) > 1 else out[0]
 
 
+def _model_replicated(x):
+    """Under a model mesh, pin ``x`` [N, ...] to the batch layout: rows
+    over the data-parallel axes, replicated over 'model'.  The
+    lookup-output gradients arrive in that layout; without the pin the
+    bucket sort's 'model' constraint propagates back into the model's
+    backward pass, which XLA then partitions along d and rounds
+    differently from the single-device step."""
+    from repro.dist import context as dctx
+    from repro.dist.exchange import model_size
+    mesh = dctx.current_mesh()
+    if mesh is None or model_size(mesh) <= 1:
+        return x
+    dp = dctx.dp_axes(mesh)
+    n_dp = int(np.prod([mesh.shape[a] for a in dp])) if dp else 1
+    lead = dp if dp and x.shape[0] % n_dp == 0 else None
+    spec = jax.sharding.PartitionSpec(lead, *([None] * (x.ndim - 1)))
+    return jax.lax.with_sharding_constraint(
+        x, jax.sharding.NamedSharding(mesh, spec))
+
+
 def from_bucketed_locations(loc: jax.Array, vals: jax.Array,
                             dense_shape: tuple[int, ...]) -> SparseGrad:
     """Bucketed (striped-layout) fast path: [N, d] locations whose column j
@@ -246,7 +266,7 @@ def from_bucketed_locations(loc: jax.Array, vals: jax.Array,
     stripe = m // d
     col = jnp.arange(d, dtype=jnp.int32)[:, None]
     lT = loc.T.astype(jnp.int32)                     # [d, N] bucket-major
-    vT = vals.reshape(n, d).T
+    vT = _model_replicated(vals).reshape(n, d).T
     off = (lT - col * stripe).astype(jnp.uint32)     # in-stripe offsets
     off, vT = _bucket_sharding(off, vT, axes=1)
     # d independent stable sorts; stability keeps coincident slots in

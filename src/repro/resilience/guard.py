@@ -103,7 +103,7 @@ def make_step(loss_fn: Callable, optimizer: Optimizer, *,
               report_touched: bool = False):
     """Build the jitted train step.
 
-    Returns ``step(params, opt_state, batch, fault_scale) ->
+    Returns ``step(params, opt_state, batch, fault_scale, *loss_args) ->
     (params, opt_state, loss, metrics, ok, grads_ok)`` where ``ok`` is the
     in-jit verdict (False -> the update was skipped and state is bit-identical
     to the input) and ``grads_ok`` distinguishes bad-gradient skips from
@@ -116,13 +116,18 @@ def make_step(loss_fn: Callable, optimizer: Optimizer, *,
     feeds to ``CheckpointManager.mark_dirty_slots`` for delta checkpoints.
     The indices are reported even for skipped steps; the trainer only marks
     them when ``ok``.
+
+    ``loss_args`` reach ``loss_fn(params, batch, *loss_args)`` as jit
+    arguments: device-resident inputs such as the D' signature buffers
+    (GBs at Criteo width) must come in this way, since an array the step
+    closed over would be embedded in the compiled program as a constant.
     """
     vg = (sparse_lib.sparse_value_and_grad(loss_fn) if sparse_grads
           else jax.value_and_grad(loss_fn, has_aux=True))
     true = jnp.asarray(True)
 
-    def step(params, opt_state, batch, fault_scale):
-        (loss, metrics), grads = vg(params, batch)
+    def step(params, opt_state, batch, fault_scale, *loss_args):
+        (loss, metrics), grads = vg(params, batch, *loss_args)
         grads = _scale_grads(grads, fault_scale)
         touched = (touched_indices(grads),) if report_touched else ()
         if not guard:

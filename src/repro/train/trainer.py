@@ -135,8 +135,12 @@ class Trainer:
                  optimizer: Optimizer, batch_fn: Callable[[int], dict],
                  donate: bool = True, sparse_grads: bool | None = None,
                  faults: faults_lib.FaultInjector | None = None,
-                 tier=None):
+                 tier=None, loss_args: tuple = ()):
         """``batch_fn(step) -> host batch dict`` (seekable by step).
+
+        ``loss_args``: extra arguments of ``loss_fn(params, batch,
+        *loss_args)``, passed to the jitted step on every call (never
+        closed over) — the embedding buffers of a recsys model.
 
         ``tier``: a :class:`repro.tier.training.TierController` when the
         memory pool exceeds the per-device budget.  The trainer then runs
@@ -163,6 +167,7 @@ class Trainer:
         """
         self.cfg = cfg
         self.loss_fn = loss_fn
+        self.loss_args = tuple(loss_args)
         self.optimizer = optimizer
         self.params = params
         self.opt_state = optimizer.init(params)
@@ -310,7 +315,7 @@ class Trainer:
             if delay:
                 time.sleep(delay)  # inside the timed region: a straggler
             out = self._jit_step(self.params, self.opt_state, batch,
-                                 np.float32(fault))
+                                 np.float32(fault), *self.loss_args)
             (self.params, self.opt_state, loss, metrics, ok, grads_ok) = \
                 out[:6]
             loss.block_until_ready()

@@ -12,8 +12,8 @@ except ImportError:
     HAVE_HYPOTHESIS = False
 
 from repro.core.minhash import jaccard_from_sets
-from repro.core.signatures import (build_signature_store, densify_store,
-                                   synthetic_dense_store,
+from repro.core.signatures import (DenseSignatureStore, build_signature_store,
+                                   densify_store, synthetic_dense_store,
                                    synthetic_signature_store)
 from repro.data.graph import NeighborSampler, molecule_batch, pad_block, sbm_graph
 from repro.data.lm_data import LMGenerator
@@ -128,6 +128,64 @@ def test_densify_matches_csr():
     for v in range(50):
         want = flat[offs[v]: offs[v] + 16]
         np.testing.assert_array_equal(sets_np[v, : len(want)], want)
+
+
+def _loop_build(rows, n_values, max_per_value, n_samples):
+    """The per-value loop build_signature_store replaced (the reference)."""
+    buckets = [[] for _ in range(n_values)]
+    for sample_id, row in enumerate(rows):
+        if n_samples is not None and sample_id >= n_samples:
+            break
+        for v in np.asarray(row).ravel():
+            b = buckets[int(v)]
+            if len(b) < max_per_value:
+                b.append(sample_id)
+    lengths = np.array([len(b) for b in buckets], dtype=np.int32)
+    offsets = np.zeros(n_values + 1, dtype=np.int32)
+    np.cumsum(lengths, out=offsets[1:])
+    flat = np.empty(int(offsets[-1]), dtype=np.uint32)
+    for v, b in enumerate(buckets):
+        flat[offsets[v]: offsets[v + 1]] = b
+    return flat, offsets, lengths
+
+
+def _loop_densify(flat, offsets, lengths, max_set, n_rows):
+    """The per-value loop densify_store replaced (the reference)."""
+    n = lengths.shape[0]
+    rows = max(n_rows or n, n)
+    sets = np.full((rows, max_set), DenseSignatureStore.PAD, np.uint32)
+    for v in range(n):
+        k = min(int(lengths[v]), max_set)
+        sets[v, :k] = flat[offsets[v]: offsets[v] + k]
+    out_len = np.zeros(rows, np.int32)
+    out_len[:n] = np.minimum(lengths, max_set)
+    return sets, out_len
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_vectorized_store_build_matches_loop(seed):
+    """build_signature_store / densify_store are byte-identical to the
+    per-value loops, on ragged multi-hot rows with repeats, empty values,
+    head caps, an n_samples cut and row padding."""
+    rng = np.random.default_rng(seed)
+    n_values = int(rng.integers(1, 60))
+    rows = [rng.integers(0, n_values, int(rng.integers(0, 9)))
+            for _ in range(int(rng.integers(0, 80)))]
+    cap, max_set = int(rng.integers(1, 12)), int(rng.integers(1, 10))
+    n_samples = None if seed % 2 else int(rng.integers(0, 60))
+    n_rows = n_values + int(rng.integers(0, 5))
+    store = build_signature_store(iter(rows), n_values, max_per_value=cap,
+                                  n_samples=n_samples)
+    want = _loop_build(rows, n_values, cap, n_samples)
+    for got, ref in zip((store.flat, store.offsets, store.lengths), want):
+        got = np.asarray(got)
+        assert got.dtype == ref.dtype and got.tobytes() == ref.tobytes()
+    dense = densify_store(store, max_set, n_rows=n_rows)
+    for got, ref in zip((dense.sets, dense.lengths),
+                        _loop_densify(*want, max_set, n_rows)):
+        got = np.asarray(got)
+        assert got.dtype == ref.dtype and got.shape == ref.shape
+        assert got.tobytes() == ref.tobytes()
 
 
 def test_densify_row_padding():

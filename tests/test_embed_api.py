@@ -131,7 +131,11 @@ def test_pr2_checkpoint_restores_unchanged():
     step, tree = mgr.restore()
     assert step == 60
     table = EmbeddingTable(_golden_cfg(g, "lma"))
-    fresh = table.init(jax.random.key(0))
+    # the checkpoint was written under the PRNG mode of its time: jax
+    # flipped jax_threefry_partitionable on by default in 0.5, which
+    # changes every jax.random draw (the pool init) but not the lookup
+    with jax.threefry_partitionable(False):
+        fresh = table.init(jax.random.key(0))
     assert sorted(tree["params"]["embedding"]) == sorted(fresh)
     for k in fresh:
         np.testing.assert_array_equal(np.asarray(tree["params"]["embedding"][k]),
@@ -244,9 +248,10 @@ def test_resolver_fused_rejects_pool_size_mismatch():
 
 def test_resolver_sharded_under_mesh():
     from repro.dist.context import use_mesh
+    from repro.launch.mesh import make_mesh
     cfg = _mem_cfg()
     params = EmbeddingTable(cfg).init(jax.random.key(0))
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     with use_mesh(mesh):
         b = resolve_backend(cfg, params)
     assert isinstance(b, bke.ShardedBackend)
@@ -456,10 +461,11 @@ import numpy as np
 import jax, jax.numpy as jnp
 from repro.core.memory import lookup
 from repro.dist.context import use_mesh
+from repro.launch.mesh import make_mesh
 from repro.embed import EmbeddingConfig, EmbeddingTable, get_scheme
 from repro.embed import backends as bke
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 cfg = EmbeddingConfig(kind="freq", vocab_sizes=(300, 200), dim=16,
                       budget=4096, seed=3, options=(("hot_k", 32),))
 table = EmbeddingTable(cfg)

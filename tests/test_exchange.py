@@ -293,10 +293,11 @@ import jax, jax.numpy as jnp
 from repro.core.signatures import synthetic_dense_store
 from repro.dist import exchange as exl
 from repro.dist.context import use_mesh
+from repro.launch.mesh import make_mesh
 from repro.embed import EmbeddingTable, get_scheme, list_schemes
 
 assert len(jax.devices()) == 8
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 rng = np.random.default_rng(0)
 
 for kind in list_schemes():
@@ -354,12 +355,13 @@ import jax, jax.numpy as jnp
 from repro.core.signatures import synthetic_dense_store
 from repro.dist import exchange as exl
 from repro.dist.context import use_mesh
+from repro.launch.mesh import make_mesh
 from repro.embed import EmbeddingTable, get_scheme
 from repro.optim import optimizers as opt_lib
 from repro.optim import sparse as sp
 
 assert len(jax.devices()) == 8
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 
 for kind in ("lma", "hashed_row", "freq"):
     scheme = get_scheme(kind)
@@ -426,11 +428,12 @@ import jax, jax.numpy as jnp
 from repro.core.signatures import synthetic_dense_store
 from repro.dist import exchange as exl
 from repro.dist.context import use_mesh
+from repro.launch.mesh import make_mesh
 from repro.dist.sharded_memory import shard_csr, shard_csr_buffers
 from repro.embed import EmbeddingTable, get_scheme
 
 assert len(jax.devices()) == 8
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 rng = np.random.default_rng(0)
 
 # a ragged CSR signature store built from the dense synthetic one
@@ -489,11 +492,12 @@ import jax, jax.numpy as jnp
 from repro.core.signatures import synthetic_dense_store
 from repro.dist import exchange as exl
 from repro.dist.context import use_mesh
+from repro.launch.mesh import make_mesh
 from repro.embed import EmbeddingTable, get_scheme, list_schemes
 import repro.kernels.fused_embed.ops as fe
 
 assert len(jax.devices()) == 8
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 rng = np.random.default_rng(0)
 
 def build(kind):
@@ -568,6 +572,7 @@ from repro.core.allocation import alloc_hashed_elem
 from repro.core.memory import init_memory, lookup
 from repro.dist import exchange as exl
 from repro.dist.context import use_mesh
+from repro.launch.mesh import make_mesh
 from repro.dist.sharded_memory import sharded_hashed_lookup
 import repro.kernels.fused_embed.ops as fe
 
@@ -594,7 +599,7 @@ fe.fused_chunk_gather_pallas = spy_gather
 
 mem = init_memory(jax.random.key(0), m, "normal", 0.1)
 gids = jnp.asarray(np.random.default_rng(1).integers(0, 4096, (B,), np.int32))
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 oracle = np.asarray(lookup(mem, alloc_hashed_elem(gids, d, m, 7)))
 for name in ("ring", "all_to_all"):
     exl.FORCED = name

@@ -632,6 +632,7 @@ from repro.core.allocation import alloc_hashed_elem
 from repro.core.memory import init_memory, lookup
 from repro.dist import exchange as exl
 from repro.dist.context import use_mesh
+from repro.launch.mesh import make_mesh
 from repro.dist.sharded_memory import sharded_hashed_lookup
 from repro.resilience import faults as flt
 from repro.resilience.exchange_guard import ExchangeGuard
@@ -640,7 +641,7 @@ from repro.resilience.health import Health
 m, d, B = 1 << 15, 16, 256
 mem = init_memory(jax.random.key(0), m, "normal", 0.1)
 gids = jnp.asarray(np.random.default_rng(1).integers(0, 4096, (B,), np.int32))
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 
 # the injected chunk drop reaches every chunked strategy via _resolve's wrap
 flt.install(flt.FaultInjector("drop_chunk@0"))

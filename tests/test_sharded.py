@@ -29,9 +29,10 @@ from repro.core.memory import init_memory, lookup
 from repro.core.signatures import synthetic_dense_store
 from repro.dist.sharded_memory import sharded_hashed_lookup, sharded_lma_lookup
 from repro.dist.context import use_mesh
+from repro.launch.mesh import make_mesh
 
 assert len(jax.devices()) == 8, jax.devices()
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 
 M_BUDGET = 4096            # divisible by model axis 4
 N_VALUES = 512             # divisible by 4 (dense store rows shard over model)
@@ -95,7 +96,7 @@ np.testing.assert_array_equal(np.asarray(got2), np.asarray(want2))
 print("2d batch OK")
 
 # ---- multi-pod mesh (pod axis joins the dp set)
-mesh3 = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh3 = make_mesh((2, 2, 2), ("pod", "data", "model"))
 with use_mesh(mesh3):
     got3 = sharded_lma_lookup(mem, store.sets, store.lengths, gids, lma,
                               mesh3, ("pod", "data"))
@@ -169,10 +170,11 @@ import jax
 import jax.numpy as jnp
 
 from repro.dist.flash_decode import sharded_flash_decode
+from repro.launch.mesh import make_mesh
 from repro.nn.attention import blocked_attention, quantize_kv, dequantize_kv
 
 assert len(jax.devices()) == 8
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 
 B, L, KV, G, hd = 4, 64, 2, 3, 16
 H = KV * G

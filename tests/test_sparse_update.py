@@ -660,12 +660,13 @@ import numpy as np
 import jax, jax.numpy as jnp
 from repro.core.signatures import synthetic_dense_store
 from repro.dist.context import use_mesh
+from repro.launch.mesh import make_mesh
 from repro.embed import EmbeddingTable, get_scheme
 from repro.optim import optimizers as opt_lib
 from repro.optim import sparse as sp
 
 assert len(jax.devices()) == 8
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 
 for kind in ("lma", "hashed_row", "freq"):
     scheme = get_scheme(kind)

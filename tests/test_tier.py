@@ -342,12 +342,12 @@ def test_launcher_maybe_tier_is_genuinely_budget_bounded():
     and a budget that staging alone exhausts is refused, never silently
     over-allocated."""
     from repro.configs.base import get_config
-    from repro.launch.train import MOMENT_LEAVES, _maybe_tier, _recsys_setup
+    from repro.launch.train import MOMENT_LEAVES, _maybe_tier, recsys_setup
     from repro.models import recsys
 
     arch = get_config("din")
     cfg = arch.make_model(None)
-    gen, bufs, batch_fn, _ = _recsys_setup(arch, cfg, 300, 2)
+    gen, bufs, batch_fn, _ = recsys_setup(arch, cfg, 300, 2)
     params = recsys.init(jax.random.key(0), cfg)
     m = int(params["embedding"]["memory"].shape[0])
     budget_mb = 32.0
@@ -371,7 +371,7 @@ def test_launcher_maybe_tier_is_genuinely_budget_bounded():
     # set, so no budget below its resident size can tier it
     arch_c = get_config("lma-dlrm-criteo")
     cfg_c = arch_c.make_model(None)
-    gen, bufs_c, batch_fn_c, _ = _recsys_setup(arch_c, cfg_c, 300, 4)
+    gen, bufs_c, batch_fn_c, _ = recsys_setup(arch_c, cfg_c, 300, 4)
     params_c = recsys.init(jax.random.key(0), cfg_c)
     with pytest.raises(SystemExit, match="stage regions alone"):
         _maybe_tier(cfg_c, arch_c, params_c, bufs_c, batch_fn_c, 0.5)
