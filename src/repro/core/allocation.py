@@ -172,11 +172,15 @@ def alloc_lma_from_rows(
     return _lma_or_fallback(params, loc_lma, support, value_ids)
 
 
+@jax.named_scope("lma_locations")
 def alloc_lma(
     params: LMAParams, store: SignatureStore | DenseSignatureStore,
     value_ids: jax.Array,
 ) -> jax.Array:
-    """Full LMA allocation A_L with very-sparse fallback to A_h (paper section 5)."""
+    """Full LMA allocation A_L with very-sparse fallback to A_h (paper section 5).
+
+    Its ops (D' row gather, minhash, rehash, fallback) sit under the
+    ``lma_locations`` named scope, so a device trace groups them."""
     if isinstance(store, DenseSignatureStore):
         rows = jnp.take(store.sets, value_ids, axis=0)
         support = jnp.take(store.lengths, value_ids, axis=0)

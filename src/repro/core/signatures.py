@@ -20,6 +20,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
+
 
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
@@ -141,21 +143,27 @@ class DenseSignatureStore:
 
 def densify_store(store: SignatureStore, max_set: int,
                   n_rows: int | None = None) -> DenseSignatureStore:
-    """CSR -> fixed-width.  ``n_rows`` pads the row count (mesh divisibility)."""
-    flat = np.asarray(store.flat)
-    offsets = np.asarray(store.offsets)
-    lengths = np.asarray(store.lengths)
-    n = lengths.shape[0]
-    rows = max(n_rows or n, n)
-    sets = np.full((rows, max_set), DenseSignatureStore.PAD, np.uint32)
-    k = np.minimum(lengths, max_set)
-    v = np.repeat(np.arange(n), k)
-    j = np.arange(v.size) - np.repeat(np.cumsum(k) - k, k)
-    sets[v, j] = flat[offsets[v] + j]
-    out_len = np.zeros(rows, np.int32)
-    out_len[:n] = k
-    return DenseSignatureStore(sets=jnp.asarray(sets),
-                               lengths=jnp.asarray(out_len))
+    """CSR -> fixed-width.  ``n_rows`` pads the row count (mesh divisibility).
+
+    Two ``repro.obs`` spans time it: ``dprime.densify`` the host fill and
+    ``dprime.put`` the copy to the device, up to the arrays being ready."""
+    with obs.span("dprime.densify"):
+        flat = np.asarray(store.flat)
+        offsets = np.asarray(store.offsets)
+        lengths = np.asarray(store.lengths)
+        n = lengths.shape[0]
+        rows = max(n_rows or n, n)
+        sets = np.full((rows, max_set), DenseSignatureStore.PAD, np.uint32)
+        k = np.minimum(lengths, max_set)
+        v = np.repeat(np.arange(n), k)
+        j = np.arange(v.size) - np.repeat(np.cumsum(k) - k, k)
+        sets[v, j] = flat[offsets[v] + j]
+        out_len = np.zeros(rows, np.int32)
+        out_len[:n] = k
+    with obs.span("dprime.put"):
+        out = DenseSignatureStore(sets=jnp.asarray(sets),
+                                  lengths=jnp.asarray(out_len))
+        return jax.block_until_ready(out)
 
 
 def synthetic_dense_store(

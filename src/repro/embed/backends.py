@@ -5,7 +5,8 @@ Three interchangeable implementations of "[N] global ids -> [N, d]":
 ``split``
     The bit-exact oracle: materialize the [N, d] location tensor
     (``scheme.locations``) and gather with ``jnp.take`` (transpose-of-gather
-    gives the scatter-add gradient automatically).
+    gives the scatter-add gradient automatically).  The gather sits under
+    the ``pool_gather`` named scope.
 
 ``fused``
     The Pallas engine (``repro/kernels/fused_embed``): locations + pool
@@ -69,7 +70,9 @@ class SplitBackend:
 
     def lookup(self, cfg: EmbeddingConfig, scheme: Scheme, params: dict,
                buffers: dict, gids: jax.Array) -> jax.Array:
-        return lookup(params["memory"], scheme.locations(cfg, buffers, gids))
+        loc = scheme.locations(cfg, buffers, gids)
+        with jax.named_scope("pool_gather"):
+            return lookup(params["memory"], loc)
 
 
 class FusedBackend:
@@ -80,7 +83,9 @@ class FusedBackend:
         from repro.kernels.fused_embed import ops as fe
         spec = scheme.fused_spec(cfg)
         extra = scheme.fused_inputs(cfg, buffers, gids)
-        return fe.fused_lookup(spec, params["memory"], gids, *extra)
+        # one kernel, its location math included
+        with jax.named_scope("pool_gather"):
+            return fe.fused_lookup(spec, params["memory"], gids, *extra)
 
     def bag(self, cfg: EmbeddingConfig, scheme: Scheme, params: dict,
             buffers: dict, gids: jax.Array, weights: jax.Array) -> jax.Array:
@@ -145,8 +150,9 @@ class TieredBackend:
 
     def lookup(self, cfg: EmbeddingConfig, scheme: Scheme, params: dict,
                buffers: dict, gids: jax.Array) -> jax.Array:
-        return lookup(params["memory"],
-                      tiered_locations(cfg, scheme, buffers, gids))
+        loc = tiered_locations(cfg, scheme, buffers, gids)
+        with jax.named_scope("pool_gather"):
+            return lookup(params["memory"], loc)
 
 
 SPLIT = SplitBackend()
@@ -186,7 +192,8 @@ def sparse_locations(cfg: EmbeddingConfig, scheme: Scheme, params: dict,
         from repro.kernels.fused_embed import ops as fe
         spec = scheme.fused_spec(cfg)
         extra = scheme.fused_inputs(cfg, buffers, gids)
-        return fe.fused_locations(spec, gids, *extra)
+        with jax.named_scope("lma_locations"):
+            return fe.fused_locations(spec, gids, *extra)
     return scheme.locations(cfg, buffers, gids)
 
 

@@ -227,6 +227,10 @@ def main(argv=None):
     ap.add_argument("--ckpt-compact-every", type=int, default=8,
                     help="delta-chain length before forcing a full base "
                          "checkpoint (bounds restore replay cost)")
+    ap.add_argument("--profile-dir", default=None,
+                    help="write a profiler trace of the training loop "
+                         "here: the trainer's train.* host spans and the "
+                         "step's named scopes (README, 'Tracing')")
     args = ap.parse_args(argv)
     from repro.launch.compile_cache import setup_compile_cache
     print(f"compile cache: {setup_compile_cache()}")
@@ -300,7 +304,17 @@ def main(argv=None):
               "dense oracle; exchange strategy "
               f"{exl.FORCED or 'auto'})")
     trainer.install_signal_handlers()
-    out = trainer.fit()
+    if args.profile_dir:
+        # the compile cache's key leaves op metadata out by default: a step
+        # compiled before a scope existed would be served with old names
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          True)
+        jax.profiler.start_trace(args.profile_dir)
+    try:
+        out = trainer.fit()
+    finally:
+        if args.profile_dir:
+            jax.profiler.stop_trace()
     print(f"done: {out}")
     if trainer.health.any_faults():
         print(f"health: {trainer.health.summary()}")

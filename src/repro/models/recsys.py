@@ -158,6 +158,12 @@ def forward(params: dict, cfg: RecsysConfig, batch: dict,
         return _din_forward(params, cfg, batch, buffers)
     feats = cfg.table.embed_fields(params["embedding"], buffers,
                                    batch["sparse"])              # [B,F,d]
+    with jax.named_scope("dense_net"):
+        return _field_forward(params, cfg, batch, buffers, feats)
+
+
+def _field_forward(params, cfg, batch, buffers, feats):
+    """Everything of a field model after its embedding lookup -> logits."""
     B = feats.shape[0]
     if cfg.model == "dlrm":
         bot = mlp(params["bot"], batch["dense"].astype(cfg.jdtype), act=jax.nn.relu,
@@ -204,6 +210,11 @@ def _din_forward(params, cfg, batch, buffers):
     t = cfg.table
     e_hist = t.embed(params["embedding"], buffers, 0, batch["hist"])    # [B,L,d]
     e_t = t.embed(params["embedding"], buffers, 0, batch["target"])     # [B,d]
+    with jax.named_scope("dense_net"):
+        return _din_head(params, cfg, batch, e_hist, e_t)
+
+
+def _din_head(params, cfg, batch, e_hist, e_t):
     pooled = _din_attention(params, cfg, e_hist, batch["hist_mask"], e_t)
     head_in = [pooled, e_t, pooled * e_t]
     if cfg.n_dense:
@@ -214,10 +225,11 @@ def _din_forward(params, cfg, batch, buffers):
 def loss_fn(params: dict, cfg: RecsysConfig, batch: dict,
             buffers: dict | None = None):
     logits = forward(params, cfg, batch, buffers).astype(jnp.float32)
-    y = batch["label"].astype(jnp.float32)
-    # numerically-stable BCE-with-logits
-    ce = jnp.mean(jnp.maximum(logits, 0) - logits * y
-                  + jnp.log1p(jnp.exp(-jnp.abs(logits))))
+    with jax.named_scope("dense_net"):
+        y = batch["label"].astype(jnp.float32)
+        # numerically-stable BCE-with-logits
+        ce = jnp.mean(jnp.maximum(logits, 0) - logits * y
+                      + jnp.log1p(jnp.exp(-jnp.abs(logits))))
     return ce, {"ce": ce, "logits": logits}
 
 

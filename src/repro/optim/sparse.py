@@ -388,7 +388,7 @@ def sparse_value_and_grad(loss_fn: Callable, has_aux: bool = True):
 
     def vg(params, *args):
         rec = _Recorder()
-        with _tracing(rec):
+        with _tracing(rec), jax.named_scope("record"):
             loss_fn(params, *args)
         if not rec.records:
             return jax.value_and_grad(loss_fn, has_aux=has_aux)(params, *args)
@@ -408,42 +408,11 @@ def sparse_value_and_grad(loss_fn: Callable, has_aux: bool = True):
             with _tracing(_Provider(taps_)):
                 return loss_fn(p, *args)
 
-        out, (gp, gt) = jax.value_and_grad(
-            lf, argnums=(0, 1), has_aux=has_aux)(params, taps)
-
-        leaf_shape = {kp: leaf.shape for kp, leaf in flat}
-        replace = {}
-        for kp, idxs in groups.items():
-            rws = {rec.records[i].row_width for i in idxs}
-            assert len(rws) == 1, (
-                "one memory pool mixes row- and element-level sparse "
-                "records; schemes must be consistent per pool")
-            (rw,) = rws
-            m = int(leaf_shape[kp][0])
-            if rw:                                  # row-aligned pool
-                rows = jnp.concatenate(
-                    [rec.records[i].loc.reshape(-1) for i in idxs])
-                vals = jnp.concatenate(
-                    [gt[i].reshape(-1, rw) for i in idxs])
-                replace[kp] = from_locations(rows, vals, (m // rw, rw))
-            else:
-                nbs = {rec.records[i].n_buckets for i in idxs}
-                nb = nbs.pop() if len(nbs) == 1 else 0
-                if nb and all(rec.records[i].loc.ndim == 2
-                              and rec.records[i].loc.shape[1] == nb
-                              for i in idxs) and len(leaf_shape[kp]) == 1:
-                    loc = jnp.concatenate(
-                        [rec.records[i].loc for i in idxs], axis=0)
-                    vals = jnp.concatenate(
-                        [gt[i].reshape(-1, nb) for i in idxs], axis=0)
-                    replace[kp] = from_bucketed_locations(
-                        loc, vals, tuple(leaf_shape[kp]))
-                else:
-                    loc = jnp.concatenate(
-                        [rec.records[i].loc.reshape(-1) for i in idxs])
-                    vals = jnp.concatenate([gt[i].reshape(-1) for i in idxs])
-                    replace[kp] = from_locations(loc, vals,
-                                                 tuple(leaf_shape[kp]))
+        with jax.named_scope("provide"):
+            out, (gp, gt) = jax.value_and_grad(
+                lf, argnums=(0, 1), has_aux=has_aux)(params, taps)
+        with jax.named_scope("sparse_grad"):
+            replace = _build_sparse_grads(rec.records, groups, gt, flat)
 
         # swap the dead dense pool cotangents (zeros under stop_gradient —
         # unused after this, so XLA never materializes them) for SparseGrads
@@ -453,6 +422,40 @@ def sparse_value_and_grad(loss_fn: Callable, has_aux: bool = True):
         return out, grads
 
     return vg
+
+
+def _build_sparse_grads(records, groups, gt, flat) -> dict:
+    """{pool path: SparseGrad} from the record pass's locations and the
+    provide pass's tap cotangents ``gt``, one build per pool."""
+    leaf_shape = {kp: leaf.shape for kp, leaf in flat}
+    replace = {}
+    for kp, idxs in groups.items():
+        rws = {records[i].row_width for i in idxs}
+        assert len(rws) == 1, (
+            "one memory pool mixes row- and element-level sparse "
+            "records; schemes must be consistent per pool")
+        (rw,) = rws
+        m = int(leaf_shape[kp][0])
+        if rw:                                  # row-aligned pool
+            rows = jnp.concatenate([records[i].loc.reshape(-1) for i in idxs])
+            vals = jnp.concatenate([gt[i].reshape(-1, rw) for i in idxs])
+            replace[kp] = from_locations(rows, vals, (m // rw, rw))
+            continue
+        nbs = {records[i].n_buckets for i in idxs}
+        nb = nbs.pop() if len(nbs) == 1 else 0
+        if nb and all(records[i].loc.ndim == 2
+                      and records[i].loc.shape[1] == nb
+                      for i in idxs) and len(leaf_shape[kp]) == 1:
+            loc = jnp.concatenate([records[i].loc for i in idxs], axis=0)
+            vals = jnp.concatenate([gt[i].reshape(-1, nb) for i in idxs],
+                                   axis=0)
+            replace[kp] = from_bucketed_locations(loc, vals,
+                                                  tuple(leaf_shape[kp]))
+        else:
+            loc = jnp.concatenate([records[i].loc.reshape(-1) for i in idxs])
+            vals = jnp.concatenate([gt[i].reshape(-1) for i in idxs])
+            replace[kp] = from_locations(loc, vals, tuple(leaf_shape[kp]))
+    return replace
 
 
 # ------------------------------------------------------------- mesh routing
@@ -479,6 +482,7 @@ def _pool_view(arr: jax.Array, shape: tuple):
     return arr.reshape(shape)
 
 
+@jax.named_scope("pool_update")
 def _leaf_sparse_update(algo: str, g: SparseGrad, states: tuple, **hyper):
     """One sparse leaf through the kernel (or the sharded slab path)."""
     orig_shapes = tuple(s.shape for s in states)
@@ -499,6 +503,7 @@ def _leaf_sparse_update(algo: str, g: SparseGrad, states: tuple, **hyper):
     return g.map_values(lambda _: u), new_states
 
 
+@jax.named_scope("pool_update")
 def sparse_apply(p: jax.Array, u: SparseGrad) -> jax.Array:
     """``apply_updates`` for one sparse leaf: O(K) scatter-add into p."""
     vals = u.values.astype(p.dtype)

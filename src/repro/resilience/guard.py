@@ -103,6 +103,12 @@ def make_step(loss_fn: Callable, optimizer: Optimizer, *,
               report_touched: bool = False):
     """Build the jitted train step.
 
+    Its ops carry named scopes that a device trace groups by: the model's
+    own (``lma_locations``, ``pool_gather``, ``dense_net``), the sparse
+    engine's (``record`` and ``provide`` around its two passes,
+    ``sparse_grad``, ``pool_update``) and the step's (``guard_check``,
+    ``dense_update``).
+
     Returns ``step(params, opt_state, batch, fault_scale, *loss_args) ->
     (params, opt_state, loss, metrics, ok, grads_ok)`` where ``ok`` is the
     in-jit verdict (False -> the update was skipped and state is bit-identical
@@ -126,22 +132,27 @@ def make_step(loss_fn: Callable, optimizer: Optimizer, *,
           else jax.value_and_grad(loss_fn, has_aux=True))
     true = jnp.asarray(True)
 
-    def step(params, opt_state, batch, fault_scale, *loss_args):
-        (loss, metrics), grads = vg(params, batch, *loss_args)
-        grads = _scale_grads(grads, fault_scale)
-        touched = (touched_indices(grads),) if report_touched else ()
-        if not guard:
-            updates, opt_state = optimizer.update(grads, opt_state, params)
-            params = apply_updates(params, updates)
-            return (params, opt_state, loss, metrics, true, true) + touched
-
-        grads_ok = all_finite(grads, max_abs_grad)
-        ok = jnp.isfinite(loss) & grads_ok
-
-        def apply(state):
-            p, s = state
+    def update(p, s, grads):
+        # the dense leaves' update; pool leaves nest their own pool_update
+        with jax.named_scope("dense_update"):
             updates, s = optimizer.update(grads, s, p)
             return apply_updates(p, updates), s
+
+    def step(params, opt_state, batch, fault_scale, *loss_args):
+        (loss, metrics), grads = vg(params, batch, *loss_args)
+        with jax.named_scope("guard_check"):
+            grads = _scale_grads(grads, fault_scale)
+        touched = (touched_indices(grads),) if report_touched else ()
+        if not guard:
+            params, opt_state = update(params, opt_state, grads)
+            return (params, opt_state, loss, metrics, true, true) + touched
+
+        with jax.named_scope("guard_check"):
+            grads_ok = all_finite(grads, max_abs_grad)
+            ok = jnp.isfinite(loss) & grads_ok
+
+        def apply(state):
+            return update(*state, grads)
 
         params, opt_state = jax.lax.cond(
             ok, apply, lambda state: state, (params, opt_state))

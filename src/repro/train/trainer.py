@@ -55,6 +55,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.checkpoint.manager import CheckpointManager
 from repro.optim import sparse as sparse_lib
 from repro.optim.optimizers import Optimizer
@@ -62,6 +63,11 @@ from repro.resilience import faults as faults_lib
 from repro.resilience import guard as guard_lib
 from repro.resilience import integrity as integ_lib
 from repro.resilience.health import Health
+
+# host spans of one step besides the batch and the device wait: the
+# periodic log line's "host" share
+HOST_SPANS = ("train.tier", "train.dispatch", "train.sync",
+              "train.bookkeeping")
 
 
 def throughput_stats(step_times, lookups_per_step: int = 0,
@@ -182,6 +188,7 @@ class Trainer:
         self._preempted = False
         self._step_times: collections.deque[float] = collections.deque(
             maxlen=256)
+        self._log_totals = obs.totals()
         self.health = Health()
         self._consecutive_skips = 0
         self.faults = faults if faults is not None else faults_lib.from_env()
@@ -284,92 +291,155 @@ class Trainer:
 
     # ------------------------------------------------------------------- fit
     def fit(self, log: Callable[[str], None] = print) -> dict:
-        resumed = self.try_resume()
+        """Train to ``cfg.total_steps``.  Every boundary of the loop is an
+        ``repro.obs`` span (``train.resume``, then per step ``train.tier``,
+        ``train.batch``, ``train.dispatch``, ``train.wait``, ``train.sync``,
+        ``train.bookkeeping`` under the step's ``train.step``, and
+        ``train.result``), so a profile names the host's share of a step."""
+        with obs.span("train.resume"):
+            resumed = self.try_resume()
         if resumed:
             log(f"[trainer] resumed from step {self.step}")
         last_loss = float("nan")
         while self.step < self.cfg.total_steps:
-            if self._preempted:
-                log(f"[trainer] preempted at step {self.step}; checkpointing")
-                self.save(blocking=True)
-                return self._result(last_loss, preempted=True)
-            if self.faults:
-                self.faults.pre_step(self, self.step)
+            with obs.step_span("train.step", self.step):
                 if self._preempted:
-                    continue
-            if self.tier is not None:
-                # writeback previous stage -> re-tier on cadence -> plan +
-                # stage this step's cold blocks (async device_put) ->
-                # install the new compact pool.  Runs before batch_fn so
-                # the remap buffers in the batch match the installed pool.
-                self.params, self.opt_state, tinfo = self.tier.pre_step(
-                    self.step, self.params, self.opt_state)
-                if self.mgr is not None and self.mgr.delta:
-                    # the planned touch set is exactly what writeback will
-                    # commit — the tiered feed of the delta dirty set
-                    self.mgr.mark_dirty_slots(tinfo.get("touched_slots", ()))
-            batch = self.batch_fn(self.step)
-            fault = self.faults.grad_fault(self.step) if self.faults else 1.0
-            delay = self.faults.step_delay(self.step) if self.faults else 0.0
-            t0 = time.perf_counter()
-            if delay:
-                time.sleep(delay)  # inside the timed region: a straggler
-            out = self._jit_step(self.params, self.opt_state, batch,
-                                 np.float32(fault), *self.loss_args)
-            (self.params, self.opt_state, loss, metrics, ok, grads_ok) = \
-                out[:6]
-            loss.block_until_ready()
-            dt = time.perf_counter() - t0
-            self._track_straggler(dt)
-            if bool(ok):
-                self._consecutive_skips = 0
-                last_loss = float(loss)
-                if self._touched_out:
-                    # resident sparse feed: this step's SparseGrad indices
-                    # (skipped steps touch nothing, so only marked on ok)
-                    self.mgr.mark_dirty_slots(np.asarray(out[6]))
-            else:
-                self.health.skipped_steps += 1
-                if not bool(grads_ok):
-                    self.health.nonfinite_grads += 1
-                self._consecutive_skips += 1
-                log(f"[trainer] step {self.step} non-finite; skipped "
-                    f"(state untouched, {self._consecutive_skips} in a row)")
-            self.step += 1
-            if self.cfg.log_every and self.step % self.cfg.log_every == 0:
-                tp = self.throughput()
-                lk = (f" {tp['lookups_per_sec']:,.0f} lookups/s"
-                      if self.cfg.lookups_per_step else "")
-                hb = self.health.summary()
-                log(f"[trainer] step {self.step} loss {last_loss:.4f} "
-                    f"({dt*1e3:.1f} ms, {tp['steps_per_sec']:.1f} steps/s{lk})"
-                    + (f" [health: {hb}]" if hb else ""))
-            if self._consecutive_skips >= self.cfg.max_consecutive_skips:
-                self._rollback(log)
-                continue
-            if (self.cfg.ckpt_every and self.step % self.cfg.ckpt_every == 0):
-                if self.cfg.verify_pool and self._has_pool:
-                    before = self.health.quarantined_chunks
-                    self._verify_pool(log)
-                    if (self.cfg.rollback_on_quarantine
-                            and self.health.quarantined_chunks > before
-                            and self.mgr
-                            and self.mgr.latest_step() is not None):
-                        # fresh corruption at the boundary: restoring the
-                        # true bytes beats persisting zeroed rows — replay
-                        # from the last durable step instead of saving
-                        log(f"[trainer] step {self.step}: boundary scan "
-                            f"quarantined fresh corruption; rolling back")
+                    log(f"[trainer] preempted at step {self.step}; "
+                        f"checkpointing")
+                    with obs.span("train.checkpoint"):
+                        self.save(blocking=True)
+                    return self._result(last_loss, preempted=True)
+                if self.faults or self.tier is not None:
+                    with obs.span("train.tier"):
+                        self._pre_step()
+                    if self._preempted:
+                        continue
+                with obs.span("train.batch"):
+                    batch = self.batch_fn(self.step)
+                fault = (self.faults.grad_fault(self.step) if self.faults
+                         else 1.0)
+                delay = (self.faults.step_delay(self.step) if self.faults
+                         else 0.0)
+                with obs.span("train.dispatch") as dispatch:
+                    if delay:
+                        time.sleep(delay)  # inside the timed region
+                    out = self._jit_step(self.params, self.opt_state, batch,
+                                         np.float32(fault), *self.loss_args)
+                    (self.params, self.opt_state, loss, metrics, ok,
+                     grads_ok) = out[:6]
+                with obs.span("train.wait") as wait:
+                    loss.block_until_ready()
+                # dispatch to ready, an injected delay included: the
+                # straggler ring buffer and the throughput stats read it
+                dt = (wait.t1 - dispatch.t0) / 1e9
+                with obs.span("train.sync"):
+                    if bool(ok):
+                        self._consecutive_skips = 0
+                        last_loss = float(loss)
+                        if self._touched_out:
+                            # resident sparse feed: this step's SparseGrad
+                            # indices (skipped steps touch nothing, so only
+                            # marked on ok)
+                            self.mgr.mark_dirty_slots(np.asarray(out[6]))
+                    else:
+                        self.health.skipped_steps += 1
+                        if not bool(grads_ok):
+                            self.health.nonfinite_grads += 1
+                        self._consecutive_skips += 1
+                        log(f"[trainer] step {self.step} non-finite; "
+                            f"skipped (state untouched, "
+                            f"{self._consecutive_skips} in a row)")
+                with obs.span("train.bookkeeping"):
+                    self._track_straggler(dt)
+                    self.step += 1
+                    if (self.cfg.log_every
+                            and self.step % self.cfg.log_every == 0):
+                        self._log_step(log, last_loss, dt)
+                    if (self._consecutive_skips
+                            >= self.cfg.max_consecutive_skips):
                         self._rollback(log)
                         continue
-                if self.mgr:
-                    self.save(blocking=False)
+                    if (self.cfg.ckpt_every
+                            and self.step % self.cfg.ckpt_every == 0):
+                        if self._boundary(log):
+                            continue
         if self.mgr:
-            self.save(blocking=True)
-            self.mgr.wait()
+            with obs.span("train.checkpoint"):
+                self.save(blocking=True)
+                self.mgr.wait()
         return self._result(last_loss, preempted=False)
 
+    def _pre_step(self):
+        """The fault injector's and the tier controller's between-steps
+        hooks, before the step's batch is drawn."""
+        if self.faults:
+            self.faults.pre_step(self, self.step)
+            if self._preempted:
+                return
+        if self.tier is not None:
+            # writeback previous stage -> re-tier on cadence -> plan +
+            # stage this step's cold blocks (async device_put) ->
+            # install the new compact pool.  Runs before batch_fn so
+            # the remap buffers in the batch match the installed pool.
+            self.params, self.opt_state, tinfo = self.tier.pre_step(
+                self.step, self.params, self.opt_state)
+            if self.mgr is not None and self.mgr.delta:
+                # the planned touch set is exactly what writeback will
+                # commit — the tiered feed of the delta dirty set
+                self.mgr.mark_dirty_slots(tinfo.get("touched_slots", ()))
+
+    def _boundary(self, log) -> bool:
+        """The checkpoint boundary: the pool scan, then an async save.
+        True when the scan's fresh corruption rolled the run back."""
+        if self.cfg.verify_pool and self._has_pool:
+            before = self.health.quarantined_chunks
+            with obs.span("train.verify_pool"):
+                self._verify_pool(log)
+            if (self.cfg.rollback_on_quarantine
+                    and self.health.quarantined_chunks > before
+                    and self.mgr
+                    and self.mgr.latest_step() is not None):
+                # fresh corruption at the boundary: restoring the
+                # true bytes beats persisting zeroed rows — replay
+                # from the last durable step instead of saving
+                log(f"[trainer] step {self.step}: boundary scan "
+                    f"quarantined fresh corruption; rolling back")
+                self._rollback(log)
+                return True
+        if self.mgr:
+            with obs.span("train.checkpoint"):
+                self.save(blocking=False)
+        return False
+
+    def _log_step(self, log, last_loss: float, dt: float):
+        """The periodic line: loss, the last step's time, throughput, and
+        the host's split since the previous line from the span totals
+        (mean ``train.batch`` and mean other host spans per step, ms)."""
+        tp = self.throughput()
+        lk = (f" {tp['lookups_per_sec']:,.0f} lookups/s"
+              if self.cfg.lookups_per_step else "")
+        now = obs.totals()
+        prev, self._log_totals = self._log_totals, now
+
+        def since(name):
+            a, b = now.get(name, {}), prev.get(name, {})
+            return (a.get("s", 0.0) - b.get("s", 0.0),
+                    a.get("calls", 0) - b.get("calls", 0))
+
+        steps = max(since("train.batch")[1], 1)     # steps since the last line
+        host = sum(since(n)[0] for n in HOST_SPANS)
+        hb = self.health.summary()
+        log(f"[trainer] step {self.step} loss {last_loss:.4f} "
+            f"({dt*1e3:.1f} ms, {tp['steps_per_sec']:.1f} steps/s{lk}; "
+            f"batch {since('train.batch')[0] / steps * 1e3:.1f} ms, "
+            f"host {host / steps * 1e3:.1f} ms per step)"
+            + (f" [health: {hb}]" if hb else ""))
+
     def _result(self, last_loss: float, preempted: bool) -> dict:
+        with obs.span("train.result"):
+            return self._result_dict(last_loss, preempted)
+
+    def _result_dict(self, last_loss: float, preempted: bool) -> dict:
         # one constructor for every exit path: the preempted dict used to
         # silently drop straggler_steps (and would have dropped the health
         # counters), breaking dashboards that key on them.  guard_enabled +
