@@ -1,7 +1,8 @@
 """Dispatch for the sparse optimizer update: Pallas on TPU, jnp elsewhere.
 
-``sparse_update(algo, indices, values, states, **hyper)`` is the one entry
-point the optimizers call (``repro/optim/sparse.py``).  On TPU the fused
+``sparse_update(algo, indices, values, states, **hyper)`` is the entry
+point of the optimizers' gather/scatter pass (``repro/optim/sparse.py``;
+Adagrad on a large bucketed stream streams the pool there instead).  On TPU the fused
 Pallas gather -> moment-update -> scatter kernel runs compiled for BOTH
 memory-pool layouts — flat [m] slabs (element-level records) and [rows, d]
 slabs (row-mode SparseGrad: hashed_row / freq, including rowwise-Adam's

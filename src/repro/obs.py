@@ -12,7 +12,10 @@ opened inside it under that step number.
 
 The spans the program opens (``README.md``, "Tracing", lists them):
 ``train.*`` in ``repro.train.trainer.Trainer.fit``, ``dprime.densify`` and
-``dprime.put`` in ``repro.core.signatures.densify_store``.
+``dprime.put`` in ``repro.core.signatures.densify_store``.  One tally
+without a duration, ``count(name)``: ``pool_update.stripe_blocked`` and
+``pool_update.gather_scatter``, one per pool leaf each time
+``repro.optim.sparse`` traces its update, naming the path it took.
 """
 from __future__ import annotations
 
@@ -54,6 +57,12 @@ class span:
 def step_span(name: str, step: int) -> span:
     """A span that the profiler marks as step ``step``."""
     return span(name, jax.profiler.StepTraceAnnotation(name, step_num=step))
+
+
+def count(name: str) -> None:
+    """Tally one event under ``name``: a call with no duration."""
+    tot = _TOTALS.setdefault(name, [0, 0])
+    tot[1] += 1
 
 
 def totals() -> dict[str, dict]:

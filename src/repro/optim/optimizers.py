@@ -13,7 +13,10 @@ kernel (one O(K) gather -> moment-update -> scatter instead of the O(m)
 dense pass — exactly the dense update for Adagrad and momentum-less SGD),
 ``scale`` / ``clip_by_global_norm`` map over the values, ``multi_transform``
 treats them as leaves when routing by path, and ``apply_updates`` applies
-them as an O(K) scatter-add.  Dense leaves are bit-unchanged.
+them as an O(K) scatter-add.  Adagrad on a striped pool's bucketed stream
+may instead stream the pool and return its new value as a
+``repro.optim.sparse.NewValue``, which ``apply_updates`` takes as it is and
+any later transform refuses.  Dense leaves are bit-unchanged.
 """
 from __future__ import annotations
 
@@ -35,14 +38,33 @@ def _is_sparse(x) -> bool:
     return isinstance(x, SparseGrad)
 
 
+def _is_new_value(x) -> bool:
+    from repro.optim.sparse import NewValue
+    return isinstance(x, NewValue)
+
+
+def _is_leaf(x) -> bool:
+    return _is_sparse(x) or _is_new_value(x)
+
+
+def _no_new_value(x):
+    if _is_new_value(x):
+        raise TypeError(
+            "a transform after the stripe-blocked Adagrad: its update is "
+            "already the pool's new value and cannot be transformed; put "
+            "the transform before the optimizer in the chain")
+    return x
+
+
 def _gmap(fn, grads, *rest):
     """tree_map over a gradient tree with SparseGrad leaves kept opaque;
-    ``fn`` on a sparse leaf maps its values (indices untouched)."""
+    ``fn`` on a sparse leaf maps its values (indices untouched).  A
+    ``NewValue`` leaf raises."""
     def one(g, *r):
-        if _is_sparse(g):
+        if _is_sparse(_no_new_value(g)):
             return g.map_values(lambda v: fn(v, *r))
         return fn(g, *r)
-    return jax.tree_util.tree_map(one, grads, *rest, is_leaf=_is_sparse)
+    return jax.tree_util.tree_map(one, grads, *rest, is_leaf=_is_leaf)
 
 
 class _Pair:
@@ -68,9 +90,11 @@ def apply_updates(params, updates):
     def one(u, p):
         if _is_sparse(u):
             return sp.sparse_apply(p, u)
+        if _is_new_value(u):
+            return u.value
         return (p + u).astype(p.dtype)
 
-    return jax.tree_util.tree_map(one, updates, params, is_leaf=_is_sparse)
+    return jax.tree_util.tree_map(one, updates, params, is_leaf=_is_leaf)
 
 
 # ------------------------------------------------------------------ transforms
@@ -97,8 +121,9 @@ def clip_by_global_norm(max_norm: float) -> Optimizer:
     def update(g, s, p=None):
         # SparseGrad values are deduped (segment-summed), so their square-sum
         # equals the dense leaf's square-sum exactly
-        leaves = jax.tree_util.tree_leaves(g, is_leaf=_is_sparse)
-        vals = [x.values if _is_sparse(x) else x for x in leaves]
+        leaves = jax.tree_util.tree_leaves(g, is_leaf=_is_leaf)
+        vals = [x.values if _is_sparse(x) else _no_new_value(x)
+                for x in leaves]
         gn = jnp.sqrt(sum(jnp.sum(jnp.square(x.astype(jnp.float32))) for x in vals))
         factor = jnp.minimum(1.0, max_norm / jnp.maximum(gn, 1e-9))
         return _gmap(lambda x: x * factor, g), s
@@ -129,10 +154,8 @@ def adagrad(lr: float, eps: float = 1e-10, initial_acc: float = 0.0) -> Optimize
             lambda x: jnp.full_like(x, initial_acc, dtype=jnp.float32), params)
 
     def update(g, acc, p=None):
-        from repro.optim.sparse import adagrad_leaf
-        return _split_pairs(jax.tree_util.tree_map(
-            lambda x, a: _Pair(*adagrad_leaf(x, a, lr=lr, eps=eps)),
-            g, acc, is_leaf=_is_sparse))
+        from repro.optim.sparse import adagrad_tree
+        return adagrad_tree(g, acc, p, lr=lr, eps=eps)
 
     return Optimizer(init, update)
 

@@ -4,7 +4,9 @@ The paper's premise makes the pool ``M`` the dominant parameter, yet the
 dense training step materializes a full [m] gradient (the lookup VJP
 scatter-adds into ``zeros(m)``) and then runs an O(m) optimizer pass over
 every slot — while a batch touches at most ``B*L*d << m`` unique locations.
-This module replaces both with O(K) work:
+This module never builds the [m] gradient, and updates the pool either in
+O(K) random accesses or, where K is large against m, in one streaming pass
+a block of stripes at a time:
 
 ``SparseGrad``
     A registered pytree (children ``indices [K]`` / ``values [K, ...]``,
@@ -41,14 +43,32 @@ This module replaces both with O(K) work:
     (``dedup_locations``: sort + segment-sum).
 
 ``sparse_sgd`` / ``sparse_adagrad`` / ``sparse_rowwise_adam``
-    Optimizers whose sparse-leaf update is a single gather -> moment-update
-    -> scatter over the K touched slots (``repro/kernels/sparse_update``:
-    Pallas on TPU, jnp scatter elsewhere), with lazy semantics — untouched
-    slots' moments are bit-untouched, matching Adagrad's classic sparse
-    rule (for Adagrad and momentum-less SGD this is *exactly* the dense
-    update).  Dense leaves fall back to the matching dense math, so one
-    optimizer instance serves a mixed tree; the dense optimizers in
-    ``optimizers.py`` symmetrically delegate SparseGrad leaves here.
+    Optimizers with two update paths for a sparse leaf, picked at trace
+    time from the stream's layout and size (``stripe_blocked_ok``):
+
+      * gather/scatter — gather the moments at the K touched slots,
+        update them, scatter them back, and hand ``apply_updates`` K update
+        values to scatter into the parameter (``repro/kernels/
+        sparse_update``: the jnp reference on TPU and CPU), with lazy
+        semantics: untouched slots' moments are bit-untouched, matching
+        Adagrad's classic sparse rule (for Adagrad and momentum-less SGD
+        this is *exactly* the dense update).  Every algorithm, unstriped
+        pools, the sharded 'model'-mesh path and small streams take it.
+      * stripe-blocked — Adagrad on a striped pool's bucketed stream with
+        no 'model' mesh and ``K * STREAM_C >= m`` streams the pool instead
+        (``stripe_blocked_adagrad``): per block of whole stripes, the
+        block's stream slice folded to one sum per touched slot and
+        written into zeros, then dense Adagrad over the block's pool and
+        accumulator, written back in place.  Untouched slots come out bit-unchanged; the update is the
+        pool's new value (``NewValue``), which ``apply_updates`` takes as
+        it is.  On a TPU v5e one random access costs as much as streaming
+        several hundred slots, so past K/m of about 0.2% this is faster.
+
+    Dense leaves fall back to the matching dense math, so one optimizer
+    instance serves a mixed tree; the dense optimizers in
+    ``optimizers.py`` symmetrically delegate SparseGrad leaves here.  Each
+    pool leaf's path is tallied in ``repro.obs`` as it traces
+    (``pool_update.stripe_blocked`` / ``pool_update.gather_scatter``).
 
 Under a distribution mesh with a non-trivial 'model' axis the moment
 update and the parameter scatter run as masked-local shard_map bodies on
@@ -71,6 +91,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import os
 from typing import Callable, NamedTuple
 
@@ -78,6 +99,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.optim.optimizers import Optimizer, _Pair, _split_pairs
 
 ENABLED = os.environ.get("REPRO_SPARSE_GRADS", "1").lower() not in (
@@ -485,6 +507,7 @@ def _pool_view(arr: jax.Array, shape: tuple):
 @jax.named_scope("pool_update")
 def _leaf_sparse_update(algo: str, g: SparseGrad, states: tuple, **hyper):
     """One sparse leaf through the kernel (or the sharded slab path)."""
+    obs.count("pool_update.gather_scatter")
     orig_shapes = tuple(s.shape for s in states)
     states = tuple(_pool_view(s, g.dense_shape) for s in states)
     mesh = _model_mesh(g.dense_shape[0]) if states else None
@@ -519,6 +542,104 @@ def sparse_apply(p: jax.Array, u: SparseGrad) -> jax.Array:
     return out.reshape(p.shape)
 
 
+# ------------------------------------------------- stripe-blocked Adagrad
+
+@jax.tree_util.register_pytree_node_class
+@dataclasses.dataclass(frozen=True)
+class NewValue:
+    """An update that already is the parameter's new value.
+
+    The stripe-blocked Adagrad computes a pool's new value inside its own
+    pass, so ``apply_updates`` takes this leaf as it is.  Nothing may
+    transform it after the optimizer: ``optimizers._gmap`` raises on it."""
+
+    value: jax.Array
+
+    def tree_flatten(self):
+        return (self.value,), None
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        return cls(children[0])
+
+
+# m/K at which both Adagrad paths take the same time on a TPU v5e (fitted
+# from their timings, PERF.md section 6, PR 14): the stripe-blocked path
+# runs when K * STREAM_C >= m
+STREAM_C = 500
+# largest block (whole stripes, a divisor of their count) the pass writes
+# into zeros: a one-stripe block of dlrm-rm2's pool, 8.4 MB, is the
+# fastest on a TPU v5e
+BLOCK_BYTES = 16 << 20
+
+
+def stripe_blocked_ok(g: SparseGrad, p) -> bool:
+    """Does Adagrad on ``g`` stream the pool stripe block by stripe block?
+
+    Yes for a bucketed stream (a striped pool) with no model mesh, whose
+    stripes fit a block and whose K entries are many against the m slots.
+    Everything else keeps the gather/scatter pass."""
+    if p is None or not g.buckets or g.unique or len(g.dense_shape) != 1:
+        return False
+    m = int(g.dense_shape[0])
+    stripe_bytes = m // g.buckets * np.dtype(np.float32).itemsize
+    return (stripe_bytes <= BLOCK_BYTES and _model_mesh(m) is None
+            and int(g.indices.shape[0]) * STREAM_C >= m)
+
+
+@jax.named_scope("pool_update")
+def stripe_blocked_adagrad(g: SparseGrad, acc: jax.Array, p: jax.Array, *,
+                           lr, eps):
+    """Adagrad on a bucketed stream as one dense pass over the pool, a
+    block of whole stripes at a time -> (NewValue(new p), new acc).
+
+    Per block: fold the block's slice of the stripe-major stream (sorted
+    inside each stripe) into one sum per touched slot, write the sums into
+    a zero block, then ``acc += g*g`` and ``p += -lr*g/(sqrt(acc)+eps)``
+    over the block's slots, back in place into the loop-carried pool and
+    accumulator.  Untouched slots get ``acc + 0`` and ``p + (-0.0)``:
+    bit-unchanged.  Touched slots get ``sparse_adagrad_ref``'s formula on
+    the same folded sums."""
+    from repro.kernels.sparse_update.ref import fold_duplicates
+    obs.count("pool_update.stripe_blocked")
+    d, m = g.buckets, int(g.dense_shape[0])
+    stripe = m // d
+    s = max(b for b in range(1, d + 1)
+            if d % b == 0 and (b == 1 or b * stripe * 4 <= BLOCK_BYTES))
+    idx = g.indices.reshape(d // s, -1)
+    val = g.values.reshape(d // s, -1)
+    lr = jnp.asarray(lr, jnp.float32)
+    n = s * stripe
+
+    def block(b, state):
+        acc, p = state
+        lo = b * n
+        i = jax.lax.dynamic_index_in_dim(idx, b, keepdims=False) - lo
+        head, v = fold_duplicates(i, jax.lax.dynamic_index_in_dim(
+            val, b, keepdims=False))
+        # one write per touched slot, of its run's sum: the other entries
+        # go past the block and are dropped, so the indices are unique and
+        # a set replaces the add (~40% faster on a TPU v5e, PERF.md)
+        i = jnp.where(head, i, n + jnp.arange(i.shape[0], dtype=i.dtype))
+        gf = jnp.zeros((n,), v.dtype).at[i].set(
+            v, unique_indices=True, mode="drop").astype(jnp.float32)
+        ds = functools.partial(jax.lax.dynamic_slice_in_dim,
+                               start_index=lo, slice_size=n)
+        acc = jax.lax.dynamic_update_slice_in_dim(
+            acc, ds(acc) + jnp.square(gf), lo, 0)
+        # the pool's write reads the block back from the updated
+        # accumulator: reading the old one would race its in-place write,
+        # and XLA would copy the whole accumulator to order the two
+        u = (-lr * gf / (jnp.sqrt(ds(acc)) + eps)).astype(v.dtype)
+        return acc, jax.lax.dynamic_update_slice_in_dim(
+            p, ds(p) + u.astype(p.dtype), lo, 0)
+
+    with jax.named_scope("stripe_blocked"):
+        acc_, p_ = jax.lax.fori_loop(0, d // s, block,
+                                     (acc.reshape(-1), p.reshape(-1)))
+    return NewValue(p_.reshape(p.shape)), acc_.reshape(acc.shape)
+
+
 # -------------------------------------------------- leaf update entry points
 # (shared by the sparse optimizers below AND the dense optimizers'
 # SparseGrad delegation in optimizers.py — one implementation, no drift)
@@ -536,11 +657,28 @@ def sgd_leaf(g, mo, p=None, *, lr, momentum=0.0):
 
 
 def adagrad_leaf(g, acc, p=None, *, lr, eps=1e-10):
+    """Adagrad on one leaf.  A sparse leaf with its parameter ``p`` at
+    hand takes ``stripe_blocked_adagrad`` where ``stripe_blocked_ok``
+    says so (the update is then a ``NewValue``), else the gather/scatter
+    pass (a ``SparseGrad`` of update values)."""
     if is_sparse(g):
+        if stripe_blocked_ok(g, p):
+            return stripe_blocked_adagrad(g, acc, p, lr=lr, eps=eps)
         u, (acc,) = _leaf_sparse_update("adagrad", g, (acc,), lr=lr, eps=eps)
         return u, acc
     acc = acc + jnp.square(g.astype(jnp.float32))
     return (-lr * g / (jnp.sqrt(acc) + eps)).astype(g.dtype), acc
+
+
+def adagrad_tree(g, acc, p=None, *, lr, eps):
+    """(updates, acc) of ``adagrad_leaf`` over a gradient tree, each leaf
+    with its parameter when ``p`` is given."""
+    if p is None:
+        return _split_pairs(_tmap(
+            lambda x, a: _Pair(*adagrad_leaf(x, a, lr=lr, eps=eps)), g, acc))
+    return _split_pairs(_tmap(
+        lambda x, a, q: _Pair(*adagrad_leaf(x, a, q, lr=lr, eps=eps)),
+        g, acc, p))
 
 
 def adam_leaf(g, mu, nu, p=None, *, lr, b1=0.9, b2=0.999, bc1=1.0, bc2=1.0,
@@ -617,8 +755,7 @@ def sparse_adagrad(lr: float, eps: float = 1e-10,
             params)
 
     def update(g, acc, p=None):
-        return _split_pairs(_tmap(
-            lambda x, a: _Pair(*adagrad_leaf(x, a, lr=lr, eps=eps)), g, acc))
+        return adagrad_tree(g, acc, p, lr=lr, eps=eps)
 
     return Optimizer(init, update)
 

@@ -68,6 +68,8 @@ from repro.resilience.health import Health
 # periodic log line's "host" share
 HOST_SPANS = ("train.tier", "train.dispatch", "train.sync",
               "train.bookkeeping")
+# the pool-update paths the sparse optimizers tally as they trace
+POOL_PATHS = ("pool_update.stripe_blocked", "pool_update.gather_scatter")
 
 
 def throughput_stats(step_times, lookups_per_step: int = 0,
@@ -412,9 +414,10 @@ class Trainer:
         return False
 
     def _log_step(self, log, last_loss: float, dt: float):
-        """The periodic line: loss, the last step's time, throughput, and
-        the host's split since the previous line from the span totals
-        (mean ``train.batch`` and mean other host spans per step, ms)."""
+        """The periodic line: loss, the last step's time, throughput, the
+        host's split since the previous line from the span totals (mean
+        ``train.batch`` and mean other host spans per step, ms), and the
+        pool-update paths of the pool leaves traced since then."""
         tp = self.throughput()
         lk = (f" {tp['lookups_per_sec']:,.0f} lookups/s"
               if self.cfg.lookups_per_step else "")
@@ -429,10 +432,13 @@ class Trainer:
         steps = max(since("train.batch")[1], 1)     # steps since the last line
         host = sum(since(n)[0] for n in HOST_SPANS)
         hb = self.health.summary()
+        paths = ", ".join(f"{n.split('.')[1]} x{since(n)[1]}"
+                          for n in POOL_PATHS if since(n)[1])
         log(f"[trainer] step {self.step} loss {last_loss:.4f} "
             f"({dt*1e3:.1f} ms, {tp['steps_per_sec']:.1f} steps/s{lk}; "
             f"batch {since('train.batch')[0] / steps * 1e3:.1f} ms, "
             f"host {host / steps * 1e3:.1f} ms per step)"
+            + (f" [pool update traced: {paths}]" if paths else "")
             + (f" [health: {hb}]" if hb else ""))
 
     def _result(self, last_loss: float, preempted: bool) -> dict:
