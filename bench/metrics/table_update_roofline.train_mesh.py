@@ -1,0 +1,9 @@
+"""Optimizer kernels on a mesh: the HBM bytes the slowest chip's share of
+the row-sparse table update requires (``bench.table_cost.update_bytes``)
+over the ``table_update`` scope's time less its collectives', at the
+chip's HBM bandwidth, in % (``bench.mesh_scopes.roofline``)."""
+from bench import mesh_scopes
+
+
+def read(ctx):
+    return mesh_scopes.roofline(ctx, "table_update")

@@ -50,7 +50,9 @@ run.
 
 Dispatch here is owned by ``repro.embed.backends.ShardedBackend``: schemes
 with a bespoke path (lma, hashed_*) plug in directly; any other registered
-pure-location scheme rides ``sharded_location_lookup``.
+pure-location scheme rides ``sharded_location_lookup``.  The full
+baseline's per-field tables, row-sharded the same way, are served by
+``sharded_table_lookup`` (``FullScheme.sharded_lookup``).
 """
 from __future__ import annotations
 
@@ -266,6 +268,69 @@ def sharded_set_lookup(table: jax.Array, gids: jax.Array, mesh, dp_axes,
                    out_specs=P(bspec, *([None] * (gids.ndim - 1 + trail))),
                    check_vma=False)
     return fn(table, gids)
+
+
+def _table_rows(table: jax.Array, ids: jax.Array, d: int,
+                gather=None) -> jax.Array:
+    """Rows ``ids`` [n] of a [V, d] table, or of one lane-packed k rows to
+    a [V / k, k d] row (``repro.dist.sharding.row_table_shape``): gather
+    the packed rows, keep each id's slot."""
+    gather = gather or (lambda t, i: jnp.take(t, i, axis=0))
+    k = int(table.shape[1]) // d
+    if k == 1:
+        return gather(table, ids)
+    rows = gather(table, ids // k).reshape(-1, k, d)
+    return jnp.take_along_axis(rows, (ids % k)[:, None, None], axis=1)[:, 0]
+
+
+def sharded_table_lookup(tables, ids: jax.Array, d: int, mesh, dp_axes,
+                         exchange=None) -> jax.Array:
+    """Per-field rows of F 'model'-row-sharded tables, in one shard_map.
+
+    ``tables``: F tables of width d, or lane-packed to width k d, whose
+    rows 'model' divides (``repro.dist.sharding.place`` lays the full
+    baseline's tables out so); ``ids`` [B, F] field-local ids, leading dim
+    dp-sharded when divisible -> [B, F, d], bit-identical to one
+    ``jnp.take`` per field of the unpacked tables.  Each rank gathers the
+    rows its slabs own (exact zeros elsewhere) for all F fields at once,
+    and the strategy's ``partial_sum_lookup`` assembles them: psum over the
+    whole batch, or ring / all_to_all over each rank's batch chunk and an
+    all-gather of the chunks.  A lookup has no location math, so the cost
+    model prices no allocation bytes (``resolve_exchange``).  Each trace
+    tallies ``table_lookup.<strategy>`` in ``repro.obs``.
+    """
+    from repro import obs
+    n_model = _model_size(mesh)
+    F = len(tables)
+    ids = ids.astype(jnp.int32)
+    if n_model <= 1 or any(int(t.shape[0]) % n_model for t in tables):
+        return jnp.stack([_table_rows(t, ids[:, f], d)
+                          for f, t in enumerate(tables)], axis=1)
+    batch, n_flat = _local_flat(mesh, dp_axes, ids)
+    ex = _resolve(exchange, mesh, n_flat // F, F * d, None, alloc_row=0.0)
+    obs.count(f"table_lookup.{ex.name}")
+    bspec = _bspec(batch)
+
+    def body(*args):
+        tabs, ids_l = args[:F], args[F]
+
+        def local_fn(idx):
+            return (jnp.stack([_table_rows(t, idx[:, f], d, exl.local_gather)
+                               for f, t in enumerate(tabs)], axis=1),)
+
+        if ex.name == "psum":
+            out, = ex.partial_sum_lookup(local_fn, ids_l, n_model)
+            return out
+        rank = jax.lax.axis_index("model")
+        mine, = ex.partial_sum_lookup(
+            local_fn, exl.chunk_for_rank(ids_l, rank, n_model), n_model)
+        return jax.lax.all_gather(mine, "model").reshape(
+            ids_l.shape + (d,))
+
+    fn = shard_map(body, mesh=mesh,
+                   in_specs=(P("model", None),) * F + (P(bspec, None),),
+                   out_specs=P(bspec, None, None), check_vma=False)
+    return fn(*tables, ids)
 
 
 def sharded_hashed_lookup(memory: jax.Array, gids: jax.Array, d: int, m: int,

@@ -170,14 +170,81 @@ def lm_rules():
 def recsys_rules():
     """RecSys params: the paper's shared memory pool M lives sharded over
     'model' (the sharded_memory lookup's in_spec — zero reshard at the
-    shard_map boundary); baseline per-table params row-shard over EP.
-    MLP towers are tiny and replicate."""
+    shard_map boundary), and so do the full baseline's tables (the sharded
+    table lookup's in_spec; ``place`` pads and packs them, ``ROW_TABLES``);
+    the other baselines' per-table params row-shard over EP.  MLP towers
+    are tiny and replicate."""
     return [
         (r"/(embedding|linear)/memory$", [["model"]]),
-        (r"/(embedding|linear)/table_\d+$", [[EP, "model", "data", None], None]),
+        (r"/(embedding|linear)/table_\d+$", [["model"], None]),
         (r"/embedding/(q|r)_\d+$", [[EP, "model", "data", None], None]),
         (r"/embedding/proj_\d+$", [None, None]),
     ]
+
+
+# The full baseline's tables on a mesh (``ROW_TABLES``: their paths).  A
+# [V, d] f32 table with d under the TPU's 128 lanes is stored by the device
+# with its rows minor (the layout that wastes no lane), and a row gather or
+# scatter there first copies the whole table into rows-major order.  So on
+# a mesh each table is held lane-packed: k = 128 // d rows side by side in
+# one [V' / k, 128] row (row r at [r // k, (r % k) * d:][:d]), V' = V
+# rounded up to a multiple of k times the 'model' size, so that the rows
+# split evenly over 'model'; no id addresses a padded row.  The lookup and
+# the row-sparse update read the packing from the table's width
+# (``repro.dist.sharded_memory.sharded_table_lookup``).  The key names stay,
+# as the checkpoint contract asks.
+ROW_TABLES = r"/(embedding|linear)/table_\d+$"
+LANES = 128
+
+
+def row_table_shape(shape: tuple, n_model: int) -> tuple:
+    """The padded, lane-packed [V' / k, k d] shape of a [V, d] table."""
+    v, d = shape
+    k = LANES // d if d < LANES and LANES % d == 0 else 1
+    step = k * n_model
+    return (-(-v // step) * step // k, k * d)
+
+
+def placement(tree, mesh, rules, row_tables: str | None = None):
+    """(shapes, shardings) of ``tree`` placed on ``mesh`` by ``rules``:
+    ShapeDtypeStructs, each leaf whose path matches ``row_tables`` in its
+    padded, lane-packed shape (``row_table_shape``), and their
+    NamedShardings."""
+    n_model = int(dict(mesh.shape).get("model", 1))
+    paths, leaves, treedef = tree_path_strings(tree)
+    shapes, shardings = [], []
+    for p, x in zip(paths, leaves):
+        shape = tuple(x.shape)
+        if row_tables and len(shape) == 2 and re.search(row_tables, p):
+            shape = row_table_shape(shape, n_model)
+        shapes.append(jax.ShapeDtypeStruct(shape, x.dtype))
+        shardings.append(NamedSharding(
+            mesh, spec_for_path(p, shape, rules, mesh)))
+    return (jax.tree_util.tree_unflatten(treedef, shapes),
+            jax.tree_util.tree_unflatten(treedef, shardings))
+
+
+def _to_shape(x: np.ndarray, shape: jax.ShapeDtypeStruct) -> np.ndarray:
+    """``x`` padded with zero rows and packed into ``shape``, on the host."""
+    if x.shape == shape.shape:
+        return x
+    rows = shape.shape[0] * shape.shape[1] // x.shape[1]
+    return np.pad(x, [(0, rows - x.shape[0]), (0, 0)]).reshape(shape.shape)
+
+
+def place(tree, mesh, rules, row_tables: str | None = None):
+    """``tree``'s arrays on ``mesh`` as ``placement`` lays them out: a leaf
+    already so placed is kept as it is; any other goes through the host,
+    padded and packed where its shape asks, and each device receives its
+    own shard alone."""
+    shapes, shardings = placement(tree, mesh, rules, row_tables)
+
+    def one(x, shape, sh):
+        if x.shape == shape.shape and getattr(x, "sharding", None) == sh:
+            return x
+        return jax.device_put(_to_shape(np.asarray(x), shape), sh)
+
+    return jax.tree_util.tree_map(one, tree, shapes, shardings)
 
 
 def gnn_rules():

@@ -17,7 +17,8 @@ Two families:
 
 ``table``
     Per-table parameters (full, qr, md); the scheme embeds directly via
-    ``embed_rows`` and no lookup backend is involved.
+    ``embed_rows`` and no lookup backend is involved.  A scheme whose rows
+    are the embeddings (``table_rows``, full) also has a sharded path.
 """
 from __future__ import annotations
 
@@ -48,6 +49,12 @@ class Scheme:
     # exchange cost model (repro.dist.exchange.sparse_worthwhile) prices the
     # d-times-smaller index vector and dedup sort.
     row_aligned: ClassVar[bool] = False
+    # True when ``table_{t}`` rows are the embeddings themselves
+    # (``embed_rows`` is a plain row gather, the full baseline):
+    # ``embed_fields`` then records each table's rows for the sparse-gradient
+    # engine, and under a mesh serves them from 'model'-sharded tables
+    # (``sharded_lookup``).
+    table_rows: ClassVar[bool] = False
     # What make_buffers consumes: None (no buffers), "signatures" (a
     # SignatureStore D', lma), or "id_counts" (per-global-id observed
     # counts, freq).  Launchers key data preparation on this.

@@ -28,6 +28,7 @@ class FullScheme(Scheme):
     kind = "full"
     family = "table"
     needs_budget = False
+    table_rows = True
 
     def build_config(self, vocab_sizes, dim, budget, **kw):
         kw.pop("budget", None)
@@ -48,6 +49,17 @@ class FullScheme(Scheme):
     def embed_rows(self, cfg, params, table, flat_ids):
         return jnp.take(params[f"table_{table}"], flat_ids.astype(jnp.int32),
                         axis=0)
+
+    def sharded_lookup(self, cfg, params, buffers, ids, mesh, dp_axes,
+                       exchange=None):
+        """ids [B, F] (field-local) -> [B, F, d] from the tables row-sharded
+        over 'model' (lane-packed, ``repro.dist.sharding.row_table_shape``):
+        one shard_map serves every field through an exchange strategy
+        (``repro.dist.sharded_memory.sharded_table_lookup``)."""
+        from repro.dist.sharded_memory import sharded_table_lookup
+        return sharded_table_lookup(
+            [params[f"table_{t}"] for t in range(cfg.n_tables)], ids,
+            cfg.dim, mesh, dp_axes, exchange=exchange)
 
 
 # ------------------------------------------------------------------- hashed

@@ -80,6 +80,47 @@ def _memory_lookup(cfg: EmbeddingConfig, params: dict, buffers: dict,
     return backend.lookup(cfg, scheme, params, buffers, gids)
 
 
+def _table_lookup(cfg: EmbeddingConfig, scheme, params: dict,
+                  ids: jax.Array) -> jax.Array:
+    """ids [B, F] -> [B, F, d] for a scheme whose table rows are the
+    embeddings, under the ``table_lookup`` scope.
+
+    Like ``_memory_lookup`` it cooperates with the sparse-gradient engine:
+    the *record* pass reports each table's rows (``record_rows``) and
+    returns zeros, the *provide* pass reads the tables behind
+    stop_gradient plus one zero tap per table, so no [V, d] table gradient
+    is made.  Under a mesh the rows come from the 'model'-sharded tables
+    through ``scheme.sharded_lookup`` (the tables lane-packed there, as
+    ``repro.dist.sharding.row_table_shape`` lays them out); with none, one
+    ``jnp.take`` per table.
+    """
+    from repro.optim import sparse as _sparse
+    B, F = ids.shape
+    keys = [f"table_{t}" for t in range(F)]
+    st = _sparse.active()
+    if st is not None and st.mode == "record":
+        for t, key in enumerate(keys):
+            # a lane-packed table holds k rows per row of its width
+            k = params[key].shape[-1] // cfg.dim
+            v = ids[:, t].astype(jnp.int32)
+            st.record_rows(params[key], v // k, cfg.dim,
+                           lanes=v % k if k > 1 else None)
+        return jnp.zeros((B, F, cfg.dim), params[keys[0]].dtype)
+    taps = None
+    if st is not None and st.mode == "provide":
+        taps = jnp.stack([st.next_tap((B, cfg.dim)) for _ in keys], axis=1)
+        params = dict(params, **{k: jax.lax.stop_gradient(params[k])
+                                 for k in keys})
+    ctx = bke.sharded_ctx()
+    with jax.named_scope("table_lookup"):
+        if ctx is not None:
+            out = scheme.sharded_lookup(cfg, params, {}, ids, *ctx)
+        else:
+            out = jnp.stack([scheme.embed_rows(cfg, params, t, ids[:, t])
+                             for t in range(F)], axis=1)
+    return out if taps is None else out + taps
+
+
 def embed(cfg: EmbeddingConfig, params: dict, buffers: dict, table: int,
           ids: jax.Array) -> jax.Array:
     """ids [...]: int -> embeddings [..., dim]."""
@@ -100,6 +141,7 @@ def embed_fields(cfg: EmbeddingConfig, params: dict, buffers: dict,
 
     Memory-family schemes take the fast path: one vectorized call over
     globalized ids — a single fused gather instead of F table gathers.
+    Row-table schemes (full) take ``_table_lookup``.
     """
     B, F = ids.shape
     assert F == cfg.n_tables, (F, cfg.n_tables)
@@ -109,6 +151,8 @@ def embed_fields(cfg: EmbeddingConfig, params: dict, buffers: dict,
         gids = (ids.astype(jnp.int32) + offs[None, :]).reshape(-1)
         out = _memory_lookup(cfg, params, buffers, gids)
         return out.reshape(B, F, cfg.dim)
+    if scheme.table_rows:
+        return _table_lookup(cfg, scheme, params, ids)
     cols = [embed(cfg, params, buffers, f, ids[:, f]) for f in range(F)]
     return jnp.stack(cols, axis=1)
 

@@ -31,6 +31,21 @@ def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...], devices=None):
                          **kw)
 
 
+def parse_mesh(spec: str, devices=None):
+    """A training mesh from ``"<data>x<model>"`` (``"1x4"``: the four chips
+    of one host over 'model'): ('data', 'model') axes on the first
+    data * model of ``devices`` (default ``jax.devices()``)."""
+    shape = tuple(int(n) for n in spec.lower().split("x"))
+    if len(shape) != 2 or min(shape) < 1:
+        raise ValueError(f"mesh {spec!r}: want <data>x<model>, e.g. 1x4")
+    devs = list(jax.devices() if devices is None else devices)
+    n = shape[0] * shape[1]
+    if len(devs) < n:
+        raise ValueError(f"mesh {spec!r} needs {n} devices, found "
+                         f"{len(devs)}")
+    return make_mesh(shape, ("data", "model"), devices=devs[:n])
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")

@@ -26,6 +26,8 @@ from repro.core.signatures import build_signature_store, densify_store
 from repro.data.lm_data import LMGenerator
 from repro.data.metrics import StreamingEval
 from repro.data.synthetic_ctr import CTRGenerator, CTRSpec, DINGenerator, DINSpec
+from repro.dist import sharding as shd
+from repro.launch.mesh import parse_mesh
 from repro.launch.steps import store_rows
 from repro.models import recsys, transformer
 from repro.optim import optimizers as opt_lib
@@ -43,11 +45,24 @@ def make_optimizer(arch, sparse_ok: bool = True):
               "sgd": lambda lr: sparse_lib.sparse_sgd(lr, momentum=0.9)}.get(
         arch.optimizer)
     if sparse_ok and sparse_lib.sparse_enabled() and sparse is not None:
-        # the memory pool routes to the explicit sparse optimizer by path;
-        # every other param keeps the arch's dense transform untouched
-        return opt_lib.multi_transform(
-            [(r"(^|/)memory$", sparse(arch.learning_rate))], default=dense)
+        # the memory pool routes to the explicit sparse optimizer by path,
+        # and with Adagrad (where the row-sparse update is the dense one)
+        # so do the full baseline's tables; every other param keeps the
+        # arch's dense transform untouched
+        rules = [(r"(^|/)memory$", sparse(arch.learning_rate))]
+        if arch.optimizer == "adagrad":
+            rules.append((r"(^|/)table_\d+$", sparse_lib.sparse_adagrad(
+                arch.learning_rate, tables=True)))
+        return opt_lib.multi_transform(rules, default=dense)
     return dense
+
+
+def row_tables(cfg) -> str | None:
+    """The paths of a recsys model's row tables, which a mesh holds padded
+    and lane-packed (``repro.dist.sharding.ROW_TABLES``): the full
+    baseline's tables, whose rows are the embeddings; None for others."""
+    return shd.ROW_TABLES if get_scheme(cfg.embedding.kind).table_rows \
+        else None
 
 
 def lookups_per_step(cfg, batch: int) -> int:
@@ -195,6 +210,13 @@ def main(argv=None):
                          "(default: REPRO_DIST_EXCHANGE or the "
                          "resolve_exchange cost model); only observable "
                          "when a distribution mesh is installed")
+    ap.add_argument("--mesh", default=None,
+                    help="train on a ('data', 'model') mesh of "
+                         "<data>x<model> devices, e.g. 1x4: parameters "
+                         "placed by repro.dist.sharding.recsys_rules (the "
+                         "full baseline's tables row-sharded over 'model'), "
+                         "the embedding lookups on their sharded paths; "
+                         "recsys archs only")
     ap.add_argument("--smoke", action="store_true",
                     help="use the reduced config (required for LM archs here)")
     ap.add_argument("--steps", type=int, default=300)
@@ -245,16 +267,29 @@ def main(argv=None):
     cfg = arch.make_smoke(**kind_kw) if (args.smoke or arch.family == "lm") \
         else arch.make_model(None, **kind_kw)
 
-    tier_ctrl = None
+    tier_ctrl = mesh = None
+    if args.mesh is not None and arch.family != "recsys":
+        raise SystemExit("--mesh trains recsys archs only")
     if arch.family == "recsys":
         gen, bufs, batch_fn, loss_fn = recsys_setup(
             arch, cfg, args.n_signatures, args.batch)
-        params = recsys.init(jax.random.key(0), cfg)
+        if args.mesh is not None:
+            mesh = parse_mesh(args.mesh)
+            # the host draws the tables whole (8.6 GB at Criteo width, more
+            # than one chip would hold beside the rest); the Trainer hands
+            # each device its own shard
+            with jax.default_device(jax.devices("cpu")[0]):
+                params = recsys.init(jax.random.key(0), cfg)
+            bufs = shd.place(bufs, mesh, shd.buffer_rules())
+            print(f"mesh {dict(mesh.shape)}")
+        else:
+            params = recsys.init(jax.random.key(0), cfg)
         from repro.tier import tier_budget_mb
         budget_mb = (args.tier_budget_mb if args.tier_budget_mb is not None
                      else tier_budget_mb())
-        params, tiered_loss, tier_ctrl = _maybe_tier(
-            cfg, arch, params, bufs, batch_fn, budget_mb)
+        if mesh is None:
+            params, tiered_loss, tier_ctrl = _maybe_tier(
+                cfg, arch, params, bufs, batch_fn, budget_mb)
         if tier_ctrl is not None:
             loss_fn = tiered_loss
     elif arch.family == "lm":
@@ -297,7 +332,9 @@ def main(argv=None):
         loss_fn, params, make_optimizer(arch, sparse_ok=tier_ctrl is None),
         batch_fn, faults=injector,
         sparse_grads=False if tier_ctrl is not None else None,
-        tier=tier_ctrl, loss_args=(bufs,))
+        tier=tier_ctrl, loss_args=(bufs,), mesh=mesh,
+        rules=shd.recsys_rules() if mesh is not None else (),
+        row_tables=row_tables(cfg) if mesh is not None else None)
     if trainer.sparse_grads:
         from repro.dist import exchange as exl
         print("sparse memory-pool updates ON (REPRO_SPARSE_GRADS=0 for the "
@@ -332,7 +369,8 @@ def main(argv=None):
         for i in range(args.eval_batches):
             b = gen.batch(2048, 700_000 + i)
             jb = {k: jnp.asarray(v) for k, v in b.items() if k != "label"}
-            ev.add(b["label"], np.asarray(fwd(eval_params, jb, bufs)))
+            with trainer.mesh_context():
+                ev.add(b["label"], np.asarray(fwd(eval_params, jb, bufs)))
         print(f"eval: {ev.compute()}")
 
 

@@ -12,10 +12,16 @@ opened inside it under that step number.
 
 The spans the program opens (``README.md``, "Tracing", lists them):
 ``train.*`` in ``repro.train.trainer.Trainer.fit``, ``dprime.densify`` and
-``dprime.put`` in ``repro.core.signatures.densify_store``.  One tally
-without a duration, ``count(name)``: ``pool_update.stripe_blocked`` and
-``pool_update.gather_scatter``, one per pool leaf each time
-``repro.optim.sparse`` traces its update, naming the path it took.
+``dprime.put`` in ``repro.core.signatures.densify_store``.  Tallies
+without a duration, ``count(name)``, each time the step is traced:
+``pool_update.stripe_blocked`` and ``pool_update.gather_scatter``, one per
+pool leaf, naming the path ``repro.optim.sparse`` took for its update;
+for the full baseline's tables ``table_lookup.<strategy>``, one per lookup
+on a mesh, naming the exchange ``repro.dist.sharded_memory
+.sharded_table_lookup`` took (psum, ring or all_to_all), and
+``table_update.sparse_rows`` or ``table_update.dense``, one per table
+leaf, naming its Adagrad path.  The step's named scopes ``table_lookup``
+and ``table_update`` hold those ops on the device.
 """
 from __future__ import annotations
 

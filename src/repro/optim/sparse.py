@@ -64,11 +64,17 @@ a block of stripes at a time:
         it is.  On a TPU v5e one random access costs as much as streaming
         several hundred slots, so past K/m of about 0.2% this is faster.
 
-    Dense leaves fall back to the matching dense math, so one optimizer
-    instance serves a mixed tree; the dense optimizers in
-    ``optimizers.py`` symmetrically delegate SparseGrad leaves here.  Each
-    pool leaf's path is tallied in ``repro.obs`` as it traces
-    (``pool_update.stripe_blocked`` / ``pool_update.gather_scatter``).
+    The full baseline's embedding tables (``table_{t}``, [V, d]) are
+    recorded row by row too (``record_rows``; lane-packed on a mesh), and
+    ``sparse_adagrad(tables=True)`` updates their rows in place
+    (``table_adagrad_leaf``): on a 'model'-sharded table through
+    ``sharded_sparse_update``, so no [V, d] gradient and no second copy
+    of a table is made.  Dense leaves fall back to the matching dense
+    math, so one optimizer instance serves a mixed tree; the dense
+    optimizers in ``optimizers.py`` symmetrically delegate SparseGrad
+    leaves here.  Each pool leaf's path is tallied in ``repro.obs`` as it
+    traces (``pool_update.stripe_blocked`` / ``pool_update.gather_scatter``),
+    each table leaf's as ``table_update.sparse_rows`` / ``.dense``.
 
 Under a distribution mesh with a non-trivial 'model' axis the moment
 update and the parameter scatter run as masked-local shard_map bodies on
@@ -93,6 +99,7 @@ import contextlib
 import dataclasses
 import functools
 import os
+import re
 from typing import Callable, NamedTuple
 
 import jax
@@ -321,6 +328,8 @@ class _Record:
     row_width: int = 0            # d when loc is [N] row ids, else 0
     n_buckets: int = 0            # d when loc columns are stripe-bucketed
     #                               (LMAParams.striped layout), else 0
+    lanes: jax.Array | None = None  # [N] slot of each row in a lane-packed
+    #                                 row of width row_width, else None
 
 
 class _Recorder:
@@ -338,11 +347,17 @@ class _Recorder:
         self.records.append(_Record(memory, loc, tuple(loc.shape),
                                     memory.dtype, n_buckets=n_buckets))
 
-    def record_rows(self, memory, rows, d: int):
-        """Row-aligned pool rows [N] (hashed_row / freq): one index per row,
-        the [N, d] tap grad becomes the row delta directly."""
+    def record_rows(self, memory, rows, d: int, lanes=None):
+        """Row-aligned pool rows [N] (hashed_row / freq, a table's rows):
+        one index per row, the [N, d] tap grad becomes the row delta
+        directly.  ``lanes``: for a table packed k rows to a row of width
+        ``memory.shape[-1]`` (``repro.dist.sharding.row_table_shape``),
+        ``rows`` are packed rows and ``lanes`` [N] each id's slot in its
+        row; the delta then fills that slot and zeros the others."""
+        width = d if lanes is None else int(memory.shape[-1])
         self.records.append(_Record(memory, rows, (rows.shape[0], d),
-                                    memory.dtype, row_width=d))
+                                    memory.dtype, row_width=width,
+                                    lanes=lanes))
 
 
 class _Provider:
@@ -378,9 +393,13 @@ def active():
 
 # ----------------------------------------------------------- grad transform
 
-def _is_memory_key(kp) -> bool:
+def _last_key(kp) -> str:
     last = kp[-1]
-    return str(getattr(last, "key", last)) == "memory"
+    return str(getattr(last, "key", last))
+
+
+def _is_memory_key(kp) -> bool:
+    return _last_key(kp) == "memory"
 
 
 def has_memory(params) -> bool:
@@ -389,9 +408,18 @@ def has_memory(params) -> bool:
     return any(_is_memory_key(kp) for kp, _ in flat)
 
 
+def has_sparse_leaves(params) -> bool:
+    """Does the tree hold a leaf whose gradient the embed layer can record
+    sparsely: a 'memory' pool or an embedding table ``table_{t}``?"""
+    flat, _ = jax.tree_util.tree_flatten_with_path(params)
+    return any(_is_memory_key(kp) or re.fullmatch(r"table_\d+", _last_key(kp))
+               for kp, _ in flat)
+
+
 def sparse_value_and_grad(loss_fn: Callable, has_aux: bool = True):
     """``fn(params, *args) -> ((loss, aux), grads)`` with SparseGrad leaves
-    for every memory pool the loss looked up; all other leaves dense.
+    for every memory pool (and row table) the loss looked up; all other
+    leaves dense.
 
     Falls back to plain ``jax.value_and_grad`` when nothing records (table-
     family schemes, or a loss with no embedding at all).
@@ -446,6 +474,19 @@ def sparse_value_and_grad(loss_fn: Callable, has_aux: bool = True):
     return vg
 
 
+def _row_deltas(r: _Record, g: jax.Array) -> jax.Array:
+    """A row record's [N, row_width] deltas from its tap cotangent: the
+    cotangent itself, or for lane-packed rows the cotangent in each id's
+    slot and exact zeros in the others."""
+    if r.lanes is None:
+        return g.reshape(-1, r.row_width)
+    d = r.tap_shape[-1]
+    k = r.row_width // d
+    g = g.reshape(-1, 1, d)
+    slot = r.lanes.reshape(-1, 1, 1) == jnp.arange(k).reshape(1, k, 1)
+    return jnp.where(slot, g, jnp.zeros((), g.dtype)).reshape(-1, r.row_width)
+
+
 def _build_sparse_grads(records, groups, gt, flat) -> dict:
     """{pool path: SparseGrad} from the record pass's locations and the
     provide pass's tap cotangents ``gt``, one build per pool."""
@@ -458,10 +499,13 @@ def _build_sparse_grads(records, groups, gt, flat) -> dict:
             "records; schemes must be consistent per pool")
         (rw,) = rws
         m = int(leaf_shape[kp][0])
-        if rw:                                  # row-aligned pool
+        if rw:                      # row-aligned pool, or a [V, d] table
             rows = jnp.concatenate([records[i].loc.reshape(-1) for i in idxs])
-            vals = jnp.concatenate([gt[i].reshape(-1, rw) for i in idxs])
-            replace[kp] = from_locations(rows, vals, (m // rw, rw))
+            vals = jnp.concatenate([_row_deltas(records[i], gt[i])
+                                    for i in idxs])
+            shape = (tuple(leaf_shape[kp]) if len(leaf_shape[kp]) == 2
+                     else (m // rw, rw))
+            replace[kp] = from_locations(rows, vals, shape)
             continue
         nbs = {records[i].n_buckets for i in idxs}
         nb = nbs.pop() if len(nbs) == 1 else 0
@@ -506,8 +550,14 @@ def _pool_view(arr: jax.Array, shape: tuple):
 
 @jax.named_scope("pool_update")
 def _leaf_sparse_update(algo: str, g: SparseGrad, states: tuple, **hyper):
-    """One sparse leaf through the kernel (or the sharded slab path)."""
+    """One sparse pool leaf through the kernel (or the sharded slab path)."""
     obs.count("pool_update.gather_scatter")
+    return _gather_scatter(algo, g, states, **hyper)
+
+
+def _gather_scatter(algo: str, g: SparseGrad, states: tuple, **hyper):
+    """Gather the moments at ``g``'s indices, update them, scatter them
+    back: (update values as a SparseGrad, new states)."""
     orig_shapes = tuple(s.shape for s in states)
     states = tuple(_pool_view(s, g.dense_shape) for s in states)
     mesh = _model_mesh(g.dense_shape[0]) if states else None
@@ -529,6 +579,10 @@ def _leaf_sparse_update(algo: str, g: SparseGrad, states: tuple, **hyper):
 @jax.named_scope("pool_update")
 def sparse_apply(p: jax.Array, u: SparseGrad) -> jax.Array:
     """``apply_updates`` for one sparse leaf: O(K) scatter-add into p."""
+    return _scatter_apply(p, u)
+
+
+def _scatter_apply(p: jax.Array, u: SparseGrad) -> jax.Array:
     vals = u.values.astype(p.dtype)
     pv = _pool_view(p, u.dense_shape)
     mesh = _model_mesh(u.dense_shape[0])
@@ -670,6 +724,26 @@ def adagrad_leaf(g, acc, p=None, *, lr, eps=1e-10):
     return (-lr * g / (jnp.sqrt(acc) + eps)).astype(g.dtype), acc
 
 
+def table_adagrad_leaf(g, acc, p=None, *, lr, eps=1e-10):
+    """Adagrad on one embedding table ``table_{t}`` ([V, d]), under the
+    ``table_update`` scope and tallied ``table_update.sparse_rows`` or
+    ``table_update.dense``.  A SparseGrad of the rows a step looked up
+    updates those rows alone (gathered, updated and written back; on a
+    'model'-sharded table inside each rank's slab), and with the table at
+    hand the rows are applied here, so the update is the table's
+    ``NewValue``.  Untouched rows keep their value and accumulator, as
+    dense Adagrad leaves them for a zero gradient row."""
+    with jax.named_scope("table_update"):
+        if not is_sparse(g):
+            obs.count("table_update.dense")
+            return adagrad_leaf(g, acc, lr=lr, eps=eps)
+        obs.count("table_update.sparse_rows")
+        u, (acc,) = _gather_scatter("adagrad", g, (acc,), lr=lr, eps=eps)
+        if p is None or is_sparse(p):   # multi_transform passes grads then
+            return u, acc
+        return NewValue(_scatter_apply(p, u)), acc
+
+
 def adagrad_tree(g, acc, p=None, *, lr, eps):
     """(updates, acc) of ``adagrad_leaf`` over a gradient tree, each leaf
     with its parameter when ``p`` is given."""
@@ -743,11 +817,12 @@ def sparse_sgd(lr: float, momentum: float = 0.0) -> Optimizer:
     return Optimizer(init, update)
 
 
-def sparse_adagrad(lr: float, eps: float = 1e-10,
-                   initial_acc: float = 0.0) -> Optimizer:
+def sparse_adagrad(lr: float, eps: float = 1e-10, initial_acc: float = 0.0,
+                   tables: bool = False) -> Optimizer:
     """Lazy Adagrad: same ``initial_acc``/``eps`` contract as the dense
     ``optimizers.adagrad`` (the shared parametrized test pins this), with
-    the per-step cost O(K) instead of O(m)."""
+    the per-step cost O(K) instead of O(m).  ``tables=True``: the leaves
+    are embedding tables, each updated by ``table_adagrad_leaf``."""
 
     def init(params):
         return jax.tree_util.tree_map(
@@ -755,7 +830,13 @@ def sparse_adagrad(lr: float, eps: float = 1e-10,
             params)
 
     def update(g, acc, p=None):
-        return adagrad_tree(g, acc, p, lr=lr, eps=eps)
+        if not tables:
+            return adagrad_tree(g, acc, p, lr=lr, eps=eps)
+        if p is None:
+            return _split_pairs(_tmap(lambda x, a: _Pair(
+                *table_adagrad_leaf(x, a, lr=lr, eps=eps)), g, acc))
+        return _split_pairs(_tmap(lambda x, a, q: _Pair(
+            *table_adagrad_leaf(x, a, q, lr=lr, eps=eps)), g, acc, p))
 
     return Optimizer(init, update)
 

@@ -104,10 +104,10 @@ def make_step(loss_fn: Callable, optimizer: Optimizer, *,
     """Build the jitted train step.
 
     Its ops carry named scopes that a device trace groups by: the model's
-    own (``lma_locations``, ``pool_gather``, ``dense_net``), the sparse
-    engine's (``record`` and ``provide`` around its two passes,
-    ``sparse_grad``, ``pool_update``) and the step's (``guard_check``,
-    ``dense_update``).
+    own (``lma_locations``, ``pool_gather``, ``table_lookup``,
+    ``dense_net``), the sparse engine's (``record`` and ``provide`` around
+    its two passes, ``sparse_grad``, ``pool_update``, ``table_update``) and
+    the step's (``guard_check``, ``dense_update``).
 
     Returns ``step(params, opt_state, batch, fault_scale, *loss_args) ->
     (params, opt_state, loss, metrics, ok, grads_ok)`` where ``ok`` is the

@@ -46,6 +46,7 @@ preempted flag, the full health counter set, and throughput stats.
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import signal
 import time
@@ -54,9 +55,12 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
 
 from repro import obs
 from repro.checkpoint.manager import CheckpointManager
+from repro.dist import sharding as shd
+from repro.dist.context import use_mesh
 from repro.optim import sparse as sparse_lib
 from repro.optim.optimizers import Optimizer
 from repro.resilience import faults as faults_lib
@@ -70,6 +74,8 @@ HOST_SPANS = ("train.tier", "train.dispatch", "train.sync",
               "train.bookkeeping")
 # the pool-update paths the sparse optimizers tally as they trace
 POOL_PATHS = ("pool_update.stripe_blocked", "pool_update.gather_scatter")
+# the full tables' counters: the lookup's exchange, the update's path
+TABLE_COUNTERS = ("table_lookup.", "table_update.")
 
 
 def throughput_stats(step_times, lookups_per_step: int = 0,
@@ -143,8 +149,20 @@ class Trainer:
                  optimizer: Optimizer, batch_fn: Callable[[int], dict],
                  donate: bool = True, sparse_grads: bool | None = None,
                  faults: faults_lib.FaultInjector | None = None,
-                 tier=None, loss_args: tuple = ()):
+                 tier=None, loss_args: tuple = (), mesh=None,
+                 rules: list = (), row_tables: str | None = None):
         """``batch_fn(step) -> host batch dict`` (seekable by step).
+
+        ``mesh``: a ('data', 'model') mesh (``repro.launch.mesh``) to train
+        on.  ``params`` are then placed by the sharding ``rules`` (for a
+        recsys model ``repro.dist.sharding.recsys_rules``), the leaves
+        ``row_tables`` matches (the full baseline's,
+        ``sharding.ROW_TABLES``) padded and lane-packed, the optimizer
+        state follows its parameters' placement, each batch is
+        put on the mesh (rows over the data-parallel axes when they divide
+        it, else replicated), and the step is traced and run under
+        ``repro.dist.context.use_mesh``, so the embedding lookups take
+        their sharded paths.
 
         ``loss_args``: extra arguments of ``loss_fn(params, batch,
         *loss_args)``, passed to the jitted step on every call (never
@@ -177,7 +195,12 @@ class Trainer:
         self.loss_fn = loss_fn
         self.loss_args = tuple(loss_args)
         self.optimizer = optimizer
+        self.mesh = mesh
+        if mesh is not None:
+            params = shd.place(params, mesh, rules, row_tables)
         self.params = params
+        # on a mesh each state leaf is made like its committed parameter
+        # (``full_like`` / ``zeros_like`` keep the parameter's sharding)
         self.opt_state = optimizer.init(params)
         self.tier = tier
         self.batch_fn = tier.batch_fn if tier is not None else batch_fn
@@ -198,7 +221,7 @@ class Trainer:
             faults_lib.install(faults)  # manager/driver hooks see it too
         if sparse_grads is None:
             sparse_grads = (sparse_lib.sparse_enabled()
-                            and sparse_lib.has_memory(params))
+                            and sparse_lib.has_sparse_leaves(params))
         self.sparse_grads = sparse_grads
         self._has_pool = sparse_lib.has_memory(params)
         self.guard = (cfg.guard_step if cfg.guard_step is not None
@@ -318,6 +341,8 @@ class Trainer:
                         continue
                 with obs.span("train.batch"):
                     batch = self.batch_fn(self.step)
+                    if self.mesh is not None:
+                        batch = self.put_batch(batch)
                 fault = (self.faults.grad_fault(self.step) if self.faults
                          else 1.0)
                 delay = (self.faults.step_delay(self.step) if self.faults
@@ -325,8 +350,10 @@ class Trainer:
                 with obs.span("train.dispatch") as dispatch:
                     if delay:
                         time.sleep(delay)  # inside the timed region
-                    out = self._jit_step(self.params, self.opt_state, batch,
-                                         np.float32(fault), *self.loss_args)
+                    with self.mesh_context():
+                        out = self._jit_step(self.params, self.opt_state,
+                                             batch, np.float32(fault),
+                                             *self.loss_args)
                     (self.params, self.opt_state, loss, metrics, ok,
                      grads_ok) = out[:6]
                 with obs.span("train.wait") as wait:
@@ -370,6 +397,22 @@ class Trainer:
                 self.save(blocking=True)
                 self.mgr.wait()
         return self._result(last_loss, preempted=False)
+
+    def mesh_context(self):
+        """``use_mesh`` of the trainer's mesh (nothing without one): the
+        context its step is traced and run in, for eval calls alike."""
+        if self.mesh is None:
+            return contextlib.nullcontext()
+        return use_mesh(self.mesh)
+
+    def put_batch(self, batch: dict) -> dict:
+        """A batch on the mesh: rows over the data-parallel axes when they
+        divide them, else replicated."""
+        def put(x):
+            spec = shd.resolve_template([[shd.DP, "data", None]], x.shape,
+                                        self.mesh)
+            return jax.device_put(x, NamedSharding(self.mesh, spec))
+        return {k: put(v) for k, v in batch.items()}
 
     def _pre_step(self):
         """The fault injector's and the tier controller's between-steps
@@ -416,8 +459,9 @@ class Trainer:
     def _log_step(self, log, last_loss: float, dt: float):
         """The periodic line: loss, the last step's time, throughput, the
         host's split since the previous line from the span totals (mean
-        ``train.batch`` and mean other host spans per step, ms), and the
-        pool-update paths of the pool leaves traced since then."""
+        ``train.batch`` and mean other host spans per step, ms), the
+        pool-update paths of the pool leaves and the full tables' lookup
+        exchange and update paths traced since then."""
         tp = self.throughput()
         lk = (f" {tp['lookups_per_sec']:,.0f} lookups/s"
               if self.cfg.lookups_per_step else "")
@@ -434,11 +478,14 @@ class Trainer:
         hb = self.health.summary()
         paths = ", ".join(f"{n.split('.')[1]} x{since(n)[1]}"
                           for n in POOL_PATHS if since(n)[1])
+        tables = ", ".join(f"{n} x{since(n)[1]}" for n in sorted(now)
+                           if n.startswith(TABLE_COUNTERS) and since(n)[1])
         log(f"[trainer] step {self.step} loss {last_loss:.4f} "
             f"({dt*1e3:.1f} ms, {tp['steps_per_sec']:.1f} steps/s{lk}; "
             f"batch {since('train.batch')[0] / steps * 1e3:.1f} ms, "
             f"host {host / steps * 1e3:.1f} ms per step)"
             + (f" [pool update traced: {paths}]" if paths else "")
+            + (f" [tables traced: {tables}]" if tables else "")
             + (f" [health: {hb}]" if hb else ""))
 
     def _result(self, last_loss: float, preempted: bool) -> dict:
