@@ -11,25 +11,31 @@ no profiler running a span costs about a microsecond on the host.
 opened inside it under that step number.
 
 The spans the program opens (``README.md``, "Tracing", lists them):
-``train.*`` in ``repro.train.trainer.Trainer.fit``, ``dprime.densify`` and
-``dprime.put`` in ``repro.core.signatures.densify_store``.  Tallies
-without a duration, ``count(name)``, each time the step is traced:
-``pool_update.stripe_blocked`` and ``pool_update.gather_scatter``, one per
-pool leaf, naming the path ``repro.optim.sparse`` took for its update;
-for the full baseline's tables ``table_lookup.<strategy>``, one per lookup
-on a mesh, naming the exchange ``repro.dist.sharded_memory
-.sharded_table_lookup`` took (psum, ring or all_to_all), and
-``table_update.sparse_rows`` or ``table_update.dense``, one per table
-leaf, naming its Adagrad path.  The step's named scopes ``table_lookup``
-and ``table_update`` hold those ops on the device.
+``train.*`` in ``repro.train.trainer.Trainer.fit``, ``input.prefetch`` on
+its input worker thread, ``dprime.densify`` and ``dprime.put`` in
+``repro.core.signatures.densify_store``.  Tallies without a duration,
+``count(name)``: ``input.prefetch.hit`` or ``input.prefetch.miss``, one
+per training step, saying whether its batch was made ahead; and each
+time the step is traced ``pool_update.stripe_blocked`` and
+``pool_update.gather_scatter``, one per pool leaf, naming the path
+``repro.optim.sparse`` took for its update; for the full baseline's
+tables ``table_lookup.<strategy>``, one per lookup on a mesh, naming
+the exchange ``repro.dist.sharded_memory.sharded_table_lookup`` took
+(psum, ring or all_to_all), and ``table_update.sparse_rows`` or
+``table_update.dense``, one per table leaf, naming its Adagrad path.
+The step's named scopes ``table_lookup`` and ``table_update`` hold those
+ops on the device.
 """
 from __future__ import annotations
 
+import threading
 import time
 
 import jax
 
 _TOTALS: dict[str, list[int]] = {}          # name -> [total ns, calls]
+# spans close on the trainer's input worker as well as the main thread
+_LOCK = threading.Lock()
 
 
 class span:
@@ -51,10 +57,8 @@ class span:
     def __exit__(self, *exc) -> bool:
         self.t1 = time.perf_counter_ns()
         self._ann.__exit__(*exc)
-        tot = _TOTALS.get(self.name)
-        if tot is None:
-            _TOTALS[self.name] = [self.t1 - self.t0, 1]
-        else:
+        with _LOCK:
+            tot = _TOTALS.setdefault(self.name, [0, 0])
             tot[0] += self.t1 - self.t0
             tot[1] += 1
         return False
@@ -67,14 +71,17 @@ def step_span(name: str, step: int) -> span:
 
 def count(name: str) -> None:
     """Tally one event under ``name``: a call with no duration."""
-    tot = _TOTALS.setdefault(name, [0, 0])
-    tot[1] += 1
+    with _LOCK:
+        _TOTALS.setdefault(name, [0, 0])[1] += 1
 
 
 def totals() -> dict[str, dict]:
     """{name: {"s": seconds in the span, "calls": times it closed}}."""
-    return {k: {"s": ns / 1e9, "calls": n} for k, (ns, n) in _TOTALS.items()}
+    with _LOCK:
+        return {k: {"s": ns / 1e9, "calls": n}
+                for k, (ns, n) in _TOTALS.items()}
 
 
 def reset() -> None:
-    _TOTALS.clear()
+    with _LOCK:
+        _TOTALS.clear()

@@ -38,7 +38,11 @@ Fault-tolerance posture (1000+-node design, exercised at container scale):
     the elastic restore path tested in tests/test_fault_tolerance.py);
   * data pipeline is index-based (seekable), so restarts do not replay or
     skip batches, and a skipped step still advances the cursor (the faulted
-    batch is dropped, not retried forever).
+    batch is dropped, not retried forever);
+  * input prefetch: while step s runs on the device, one worker thread
+    makes (and on a mesh puts) batch s + 1, across ``fit`` calls.  A
+    trainer with a tier controller draws each batch inline instead: its
+    ``pre_step`` installs the pool that the batch's remap buffers match.
 
 ``fit`` returns one unified result dict on every exit path — step, loss,
 preempted flag, the full health counter set, and throughput stats.
@@ -46,6 +50,7 @@ preempted flag, the full health counter set, and throughput stats.
 from __future__ import annotations
 
 import collections
+import concurrent.futures
 import contextlib
 import dataclasses
 import signal
@@ -107,6 +112,17 @@ def throughput_stats(step_times, lookups_per_step: int = 0,
     return out
 
 
+@dataclasses.dataclass
+class _Ahead:
+    """A batch being made one step ahead: its step, the ``batch_fn`` that
+    makes it, the preempt flag as it stood when it was submitted, and the
+    worker's future (its result, or the exception ``batch_fn`` raised)."""
+    step: int
+    batch_fn: Callable
+    preempted: bool
+    future: concurrent.futures.Future
+
+
 def _restore_like(template, restored):
     """Rebuild ``restored`` (structure-lossy after serialization) into the tree
     structure of ``template`` (NamedTuples, custom nodes)."""
@@ -151,7 +167,12 @@ class Trainer:
                  faults: faults_lib.FaultInjector | None = None,
                  tier=None, loss_args: tuple = (), mesh=None,
                  rules: list = (), row_tables: str | None = None):
-        """``batch_fn(step) -> host batch dict`` (seekable by step).
+        """``batch_fn(step) -> host batch dict`` (seekable by step).  It
+        may be called one step ahead, from a worker thread, and once past
+        ``total_steps``, so it must be seekable and thread-safe: a batch
+        made ahead that no step takes is dropped, and an exception it
+        raised is re-raised only by the step that takes it.  The trainer
+        reads ``self.batch_fn`` each time it draws a batch.
 
         ``mesh``: a ('data', 'model') mesh (``repro.launch.mesh``) to train
         on.  ``params`` are then placed by the sharding ``rules`` (for a
@@ -213,6 +234,9 @@ class Trainer:
         self._preempted = False
         self._step_times: collections.deque[float] = collections.deque(
             maxlen=256)
+        # the input worker (started on the first prefetch) and its batch
+        self._input_pool: concurrent.futures.ThreadPoolExecutor | None = None
+        self._ahead: _Ahead | None = None
         self._log_totals = obs.totals()
         self.health = Health()
         self._consecutive_skips = 0
@@ -296,6 +320,7 @@ class Trainer:
         self.opt_state = _restore_like(self.opt_state, state["opt_state"])
         self.step = int(np.asarray(state["step"]))
         self._resumed_step = self.step
+        self._ahead = None          # the next step's batch is made inline
         if self.tier is not None:
             meta = state.get("tier") if isinstance(state, dict) else None
             if meta is not None:
@@ -320,7 +345,20 @@ class Trainer:
         ``repro.obs`` span (``train.resume``, then per step ``train.tier``,
         ``train.batch``, ``train.dispatch``, ``train.wait``, ``train.sync``,
         ``train.bookkeeping`` under the step's ``train.step``, and
-        ``train.result``), so a profile names the host's share of a step."""
+        ``train.result``), so a profile names the host's share of a step.
+
+        Once step s is dispatched, ``batch_fn(s + 1)`` runs on the input
+        worker (span ``input.prefetch``) while the device works, also when
+        s + 1 is ``total_steps``, so that the next call's first step finds
+        its batch made.  ``train.batch`` then only takes it (counter
+        ``input.prefetch.hit``); a step whose batch was not made ahead --
+        the first, one after a resume or rollback, every step of a tiered
+        trainer -- makes it inline (``input.prefetch.miss``).  The fault
+        injector's ``pre_step`` runs before the step takes its batch on
+        either path.  A step whose batch was made ahead
+        reads the preempt flag as it stood when that batch was submitted,
+        so a flag raised by ``batch_fn(s)`` still stops the run at s + 1,
+        and a signal is honoured at most one step later."""
         with obs.span("train.resume"):
             resumed = self.try_resume()
         if resumed:
@@ -328,7 +366,9 @@ class Trainer:
         last_loss = float("nan")
         while self.step < self.cfg.total_steps:
             with obs.step_span("train.step", self.step):
-                if self._preempted:
+                inline = self.tier is not None
+                ahead = None if inline else self._take_ahead()
+                if self._preempted if ahead is None else ahead.preempted:
                     log(f"[trainer] preempted at step {self.step}; "
                         f"checkpointing")
                     with obs.span("train.checkpoint"):
@@ -340,9 +380,12 @@ class Trainer:
                     if self._preempted:
                         continue
                 with obs.span("train.batch"):
-                    batch = self.batch_fn(self.step)
-                    if self.mesh is not None:
-                        batch = self.put_batch(batch)
+                    if ahead is not None:
+                        obs.count("input.prefetch.hit")
+                        batch = ahead.future.result()
+                    else:
+                        obs.count("input.prefetch.miss")
+                        batch = self._make_batch(self.batch_fn, self.step)
                 fault = (self.faults.grad_fault(self.step) if self.faults
                          else 1.0)
                 delay = (self.faults.step_delay(self.step) if self.faults
@@ -356,6 +399,8 @@ class Trainer:
                                              *self.loss_args)
                     (self.params, self.opt_state, loss, metrics, ok,
                      grads_ok) = out[:6]
+                if not inline:
+                    self._submit_ahead(self.step + 1)
                 with obs.span("train.wait") as wait:
                     loss.block_until_ready()
                 # dispatch to ready, an injected delay included: the
@@ -405,6 +450,33 @@ class Trainer:
             return contextlib.nullcontext()
         return use_mesh(self.mesh)
 
+    def _make_batch(self, batch_fn: Callable, step: int) -> dict:
+        batch = batch_fn(step)
+        return batch if self.mesh is None else self.put_batch(batch)
+
+    def _prefetch(self, batch_fn: Callable, step: int) -> dict:
+        with obs.span("input.prefetch"):
+            return self._make_batch(batch_fn, step)
+
+    def _submit_ahead(self, step: int):
+        """Start making ``step``'s batch on the input worker."""
+        if self._input_pool is None:
+            self._input_pool = concurrent.futures.ThreadPoolExecutor(
+                1, thread_name_prefix="trainer-input")
+        fn = self.batch_fn
+        self._ahead = _Ahead(step, fn, self._preempted,
+                             self._input_pool.submit(self._prefetch, fn, step))
+
+    def _take_ahead(self) -> _Ahead | None:
+        """The batch made ahead for this step, or None when the one made
+        ahead is for another step or another ``batch_fn`` (dropped
+        unwaited)."""
+        ahead, self._ahead = self._ahead, None
+        if (ahead is not None and ahead.step == self.step
+                and ahead.batch_fn is self.batch_fn):
+            return ahead
+        return None
+
     def put_batch(self, batch: dict) -> dict:
         """A batch on the mesh: rows over the data-parallel axes when they
         divide them, else replicated."""
@@ -416,7 +488,7 @@ class Trainer:
 
     def _pre_step(self):
         """The fault injector's and the tier controller's between-steps
-        hooks, before the step's batch is drawn."""
+        hooks, before the step takes its batch."""
         if self.faults:
             self.faults.pre_step(self, self.step)
             if self._preempted:
@@ -459,9 +531,10 @@ class Trainer:
     def _log_step(self, log, last_loss: float, dt: float):
         """The periodic line: loss, the last step's time, throughput, the
         host's split since the previous line from the span totals (mean
-        ``train.batch`` and mean other host spans per step, ms), the
-        pool-update paths of the pool leaves and the full tables' lookup
-        exchange and update paths traced since then."""
+        ``train.batch`` and mean other host spans per step, ms, and the
+        steps whose batch was made ahead), the pool-update paths of the
+        pool leaves and the full tables' lookup exchange and update paths
+        traced since then."""
         tp = self.throughput()
         lk = (f" {tp['lookups_per_sec']:,.0f} lookups/s"
               if self.cfg.lookups_per_step else "")
@@ -474,6 +547,7 @@ class Trainer:
                     a.get("calls", 0) - b.get("calls", 0))
 
         steps = max(since("train.batch")[1], 1)     # steps since the last line
+        hits = since("input.prefetch.hit")[1]
         host = sum(since(n)[0] for n in HOST_SPANS)
         hb = self.health.summary()
         paths = ", ".join(f"{n.split('.')[1]} x{since(n)[1]}"
@@ -483,7 +557,8 @@ class Trainer:
         log(f"[trainer] step {self.step} loss {last_loss:.4f} "
             f"({dt*1e3:.1f} ms, {tp['steps_per_sec']:.1f} steps/s{lk}; "
             f"batch {since('train.batch')[0] / steps * 1e3:.1f} ms, "
-            f"host {host / steps * 1e3:.1f} ms per step)"
+            f"host {host / steps * 1e3:.1f} ms per step; "
+            f"prefetched {hits}/{steps})"
             + (f" [pool update traced: {paths}]" if paths else "")
             + (f" [tables traced: {tables}]" if tables else "")
             + (f" [health: {hb}]" if hb else ""))
